@@ -19,6 +19,7 @@ from benchmarks import (bench_diurnal, bench_engine_throughput,
                         bench_table4_sla,
                         bench_table5_stability, bench_table6_crosshw,
                         bench_table7_live)
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = (
     ("engine_throughput", bench_engine_throughput),
@@ -48,6 +49,7 @@ def main(argv=None):
                     help="reduced request counts (~3x faster)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     t_all = time.time()
     for name, mod in SUITES:

@@ -21,6 +21,7 @@ from repro.experiments.analyze import report, write_tables
 from repro.experiments.plans import PLANS, get_plan
 from repro.experiments.runner import PlanRunner
 from repro.experiments.store import ExperimentStore
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -62,6 +63,7 @@ def main(argv=None):
                          "pool is declared wedged, killed, and unfinished "
                          "cells re-dispatched (per-cell retry budget)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     plan = get_plan(args.plan)
     store = ExperimentStore(plan.name, args.root)

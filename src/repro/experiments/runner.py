@@ -20,13 +20,16 @@ Three backends behind one `execute_cells` surface (ISSUE 4, ISSUE 7):
 * ``backend="jit"`` — the compiled fleet (`repro.serving.fleet_jit`):
   same lane partitioning, but each chunk runs the jit-compiled
   `lax.while_loop` event loop (~4x the vector backend's cells/s at 256
-  lanes). Records agree with the numpy oracle within
+  lanes, on XLA:CPU). Its chunks run in the calling process and never
+  in the pool: an accelerator belongs to one process, and a pool worker
+  that imported JAX would contend for it (only cells the fleet cannot
+  take still fan out). Records agree with the numpy oracle within
   `serving.precision.jit_tolerance()` rather than bitwise — commit
   stores with the vector/process backends, sweep with jit. Points the
   compiled loop cannot express fall back through the numpy fleet
   automatically, and checkpoint granularity is per chunk.
 
-Pooled lane chunks are *work-stolen* (ISSUE 7 satellite): instead of
+Pooled (numpy) lane chunks are *work-stolen*: instead of
 pre-slicing the lanes into fixed >=16-wide chunks (where a ragged
 lambda-ladder's slowest chunk idles every other worker at the tail),
 workers draw successive chunks from a shared queue, each sized to the
@@ -137,28 +140,24 @@ def _pool_task(cell: Cell, checkpoint=None) -> RunRecord:
 
 
 def _fleet_task(points, cells: Optional[List[Cell]] = None,
-                checkpoint=None, backend: str = "vector"
-                ) -> List[RunRecord]:
-    """Fleet-chunk pool task: run a lane chunk in one vectorized engine
-    (numpy fleet, or the compiled fleet under ``backend="jit"``).
+                checkpoint=None) -> List[RunRecord]:
+    """Fleet-chunk pool task: run a lane chunk in one vectorized numpy
+    engine.
 
     With a checkpoint handle, each lane's record is written to the store
     *from the worker* the moment the lane finishes — a chunk killed
     mid-flight (SIGKILL, OOM) loses only its in-flight lanes on resume
     instead of the whole chunk (writes are atomic; the parent's own
     `on_result` write at chunk completion is byte-identical)."""
-    if backend == "jit":
-        from repro.serving.fleet_jit import jit_run_points as _run
-    else:
-        from repro.serving.fleet import fleet_run_points as _run
+    from repro.serving.fleet import fleet_run_points
     store = _checkpoint_store(checkpoint)
     if store is None or cells is None:
-        return _run(points)
+        return fleet_run_points(points)
 
     def _ckpt(j: int, rec: RunRecord):
         store.write_cell(cells[j], rec)
 
-    return _run(points, on_result=_ckpt)
+    return fleet_run_points(points, on_result=_ckpt)
 
 
 def shutdown_pool(kill: bool = False):
@@ -254,9 +253,10 @@ def execute_cells(cells: Sequence[Cell], *,
     backend="vector" chunks fleet-eligible cells into lanes of the
     vectorized fleet simulator and composes with the pool (lanes x
     cores); records are identical to backend="process" bit-for-bit.
-    backend="jit" runs the chunks on the compiled fleet instead
-    (tolerance-equivalent records; see `serving.fleet_jit`). Pooled
-    chunks are drawn work-stealing from a shared lane queue.
+    backend="jit" runs the chunks on the compiled fleet instead, in
+    this process (tolerance-equivalent records; see
+    `serving.fleet_jit`). Pooled chunks are drawn work-stealing from a
+    shared lane queue.
 
     `checkpoint=(plan_name, store_root)` lets pool *workers* write each
     finished cell to the store themselves (atomic), so a worker killed
@@ -320,6 +320,13 @@ def execute_cells(cells: Sequence[Cell], *,
                 if on_result:
                     on_result(cells[i], results[i])
 
+    if backend == "jit":
+        # one process per accelerator: compiled chunks never enter the
+        # pool, only the cells the fleet cannot take do
+        for chunk in _chunk(lane_idx, max(1, width_cap)):
+            _run_chunk_serial(chunk)
+        lane_idx = []
+
     # pool sizing: every solo cell is a unit; lanes count as the number
     # of minimum-width chunks they could split into under work stealing
     n_units = -(-len(lane_idx) // MIN_FLEET_LANE_WIDTH) + len(solo_idx)
@@ -361,7 +368,7 @@ def execute_cells(cells: Sequence[Cell], *,
                                    for i in chunk],
                                   [cells[i] for i in chunk]
                                   if checkpoint else None,
-                                  checkpoint, backend)
+                                  checkpoint)
                 futs[fut] = chunk
                 pending.add(fut)
 

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -90,26 +88,30 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
         _fa_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_kb=num_kb)
 
-    return pl.pallas_call(
-        kernel,
-        grid=(B, Hq, num_qb, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),    # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),    # denominator
-            pltpu.VMEM((block_q, D), jnp.float32),    # output accumulator
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
+    # Mosaic has no 64-bit types: trace in 32-bit mode even where
+    # the process enabled x64 (the compiled fleet does)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel,
+            grid=(B, Hq, num_qb, num_kb),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D),
+                             lambda b, h, qi, ki: (b, h, qi, 0)),
+                pl.BlockSpec((1, 1, block_k, D),
+                             lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
+                pl.BlockSpec((1, 1, block_k, D),
+                             lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, D),
+                                   lambda b, h, qi, ki: (b, h, qi, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),    # running max
+                pltpu.VMEM((block_q, 1), jnp.float32),    # denominator
+                pltpu.VMEM((block_q, D), jnp.float32),    # output accumulator
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=interpret,
+        )(q, k, v)

@@ -15,21 +15,15 @@ from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def flash_attention(q, k, v, *, causal: bool = True,
                     use_kernel: Optional[bool] = None,
-                    interpret: Optional[bool] = None,
+                    interpret: bool = False,
                     block_q: int = 128, block_k: int = 128):
     """q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)."""
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:
         return flash_attention_ref(q, k, v, causal=causal)
-    if interpret is None:
-        interpret = not _on_tpu()
     Hq, G = q.shape[2], q.shape[2] // k.shape[2]
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _mm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_sc, *, num_kb: int):
     ki = pl.program_id(2)
@@ -53,19 +51,23 @@ def int8_matmul_kernel(x_q, w_q, x_scale, w_scale, *, block_m: int = 256,
     num_kb = K // block_k
 
     kernel = functools.partial(_mm_kernel, num_kb=num_kb)
-    return pl.pallas_call(
-        kernel,
-        grid=(M // block_m, N // block_n, num_kb),
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
-            pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((1,), lambda mi, ni, ki: (0,)),
-            pl.BlockSpec((1, block_n), lambda mi, ni, ki: (0, ni)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x_q, w_q, x_scale, w_scale)
+    # Mosaic has no 64-bit types: trace in 32-bit mode even where
+    # the process enabled x64 (the compiled fleet does)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel,
+            grid=(M // block_m, N // block_n, num_kb),
+            in_specs=[
+                pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
+                pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
+                pl.BlockSpec((1,), lambda mi, ni, ki: (0,)),
+                pl.BlockSpec((1, block_n), lambda mi, ni, ki: (0, ni)),
+            ],
+            out_specs=pl.BlockSpec((block_m, block_n),
+                                   lambda mi, ni, ki: (mi, ni)),
+            out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(x_q, w_q, x_scale, w_scale)

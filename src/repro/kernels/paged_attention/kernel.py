@@ -7,10 +7,15 @@ KV page from the HBM pool into VMEM based on block_tables[b, p]. Flash-
 decoding style running max/denominator accumulate across pages in VMEM
 scratch; invalid tail pages are skipped with @pl.when.
 
+The pool is head-major so that every block spans the full last two
+dimensions of its array, (page, D) and (G, D): Mosaic requires a block's
+last two dims to be divisible by (8, 128) or equal to the array's, and it
+refuses a block that cuts the Hkv axis out of a (P, page, Hkv, D) pool.
+
 Grid: (B, Hkv, max_pages), pages innermost/sequential.
-  q:      (B, Hq, D)        -> block (1, G, D) for the grid's kv head
-  k_pool: (P, page, Hkv, D) -> block (1, page, 1, D) at page block_tables[b,p]
-  out:    (B, Hq, D)
+  q:      (B, Hkv, G, D)    -> block (1, 1, G, D) for the grid's kv head
+  k_pool: (Hkv, P, page, D) -> block (1, 1, page, D) at page block_tables[b,p]
+  out:    (B, Hkv, G, D)
 """
 from __future__ import annotations
 
@@ -20,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -42,9 +45,9 @@ def _pa_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p * page < seq_len)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                   # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)             # (page, D)
+        q = q_ref[0, 0].astype(jnp.float32)                # (G, D)
+        k = k_ref[0, 0].astype(jnp.float32)                # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)                # (page, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # (G, page)
@@ -63,17 +66,17 @@ def _pa_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p == num_pages - 1)
     def _finish():
-        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-                    ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_kernel(q, k_pool, v_pool, block_tables, seq_lens, *,
                            interpret: bool = False):
-    """q: (B, Hq, D); pools: (P, page, Hkv, D); block_tables: (B, max_pages);
+    """q: (B, Hq, D); pools: (Hkv, P, page, D); block_tables: (B, max_pages);
     seq_lens: (B,). Returns (B, Hq, D)."""
     B, Hq, D = q.shape
-    P, page, Hkv, _ = k_pool.shape
+    Hkv, _, page, _ = k_pool.shape
     max_pages = block_tables.shape[1]
     G = Hq // Hkv
     sm_scale = 1.0 / (D ** 0.5)
@@ -85,27 +88,30 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, seq_lens, *,
         num_scalar_prefetch=2,
         grid=(B, Hkv, max_pages),
         in_specs=[
-            pl.BlockSpec((1, G, D),
-                         lambda b, h, p, bt, sl: (b, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, p, bt, sl: (bt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, p, bt, sl: (bt[b, p], 0, h, 0)),
+            pl.BlockSpec((1, 1, G, D),
+                         lambda b, h, p, bt, sl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, p, bt, sl: (h, bt[b, p], 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, p, bt, sl: (h, bt[b, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, D), lambda b, h, p, bt, sl: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, p, bt, sl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
     )
-    qg = q.reshape(B, Hkv, G, D).reshape(B, Hkv * G, D)  # group-major heads
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(block_tables, seq_lens, qg, k_pool, v_pool)
-    return out
+    # Mosaic has no 64-bit types: trace in 32-bit mode even where
+    # the process enabled x64 (the compiled fleet does)
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(block_tables, seq_lens, q.reshape(B, Hkv, G, D), k_pool, v_pool)
+    return out.reshape(B, Hq, D)

@@ -6,30 +6,30 @@ import jax.numpy as jnp
 
 
 def gather_kv(pool, block_tables):
-    """pool: (P, page, Hkv, D); block_tables: (B, max_pages) ->
-    contiguous (B, max_pages*page, Hkv, D)."""
-    gathered = pool[block_tables]            # (B, max_pages, page, Hkv, D)
-    B, n, pg, H, D = gathered.shape
-    return gathered.reshape(B, n * pg, H, D)
+    """pool: (Hkv, P, page, D); block_tables: (B, max_pages) ->
+    contiguous (B, Hkv, max_pages*page, D)."""
+    gathered = pool[:, block_tables]         # (Hkv, B, max_pages, page, D)
+    H, B, n, pg, D = gathered.shape
+    return gathered.reshape(H, B, n * pg, D).transpose(1, 0, 2, 3)
 
 
 def paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens):
-    """q: (B, Hq, D); pools: (P, page, Hkv, D); block_tables: (B, max_pages)
+    """q: (B, Hq, D); pools: (Hkv, P, page, D); block_tables: (B, max_pages)
     int32; seq_lens: (B,) int32 valid context lengths.
 
     Returns (B, Hq, D).
     """
     B, Hq, D = q.shape
-    k = gather_kv(k_pool, block_tables)      # (B, S, Hkv, D)
+    k = gather_kv(k_pool, block_tables)      # (B, Hkv, S, D)
     v = gather_kv(v_pool, block_tables)
-    Hkv = k.shape[2]
+    Hkv = k.shape[1]
     G = Hq // Hkv
-    S = k.shape[1]
+    S = k.shape[2]
     qg = q.reshape(B, Hkv, G, D)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qg, k).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k).astype(jnp.float32)
     s = s / jnp.sqrt(jnp.float32(D))
     mask = jnp.arange(S)[None] < seq_lens[:, None]          # (B, S)
     s = jnp.where(mask[:, None, None], s, -1e30)            # finite: matches
     p = jax.nn.softmax(s, axis=-1)                          # kernel at len=0
-    o = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v.dtype), v)
+    o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(v.dtype), v)
     return o.reshape(B, Hq, D).astype(q.dtype)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
                  y_ref, hout_ref, h_sc, *, chunk: int, num_chunks: int):
@@ -66,29 +64,35 @@ def ssm_scan_kernel(u, dt, Bm, Cm, A, D, h0, *, chunk: int = 256,
 
     kernel = functools.partial(_scan_kernel, chunk=chunk,
                                num_chunks=num_chunks)
-    y, h = pl.pallas_call(
-        kernel,
-        grid=(B, di // block_di, num_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, block_di), lambda b, dk, ci: (b, ci, dk)),
-            pl.BlockSpec((1, chunk, block_di), lambda b, dk, ci: (b, ci, dk)),
-            pl.BlockSpec((1, chunk, N), lambda b, dk, ci: (b, ci, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, dk, ci: (b, ci, 0)),
-            pl.BlockSpec((block_di, N), lambda b, dk, ci: (dk, 0)),
-            pl.BlockSpec((block_di,), lambda b, dk, ci: (dk,)),
-            pl.BlockSpec((1, block_di, N), lambda b, dk, ci: (b, dk, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, block_di), lambda b, dk, ci: (b, ci, dk)),
-            pl.BlockSpec((1, block_di, N), lambda b, dk, ci: (b, dk, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, S, di), jnp.float32),
-            jax.ShapeDtypeStruct((B, di, N), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_di, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(u, dt, Bm, Cm, A, D, h0)
+    # Mosaic has no 64-bit types: trace in 32-bit mode even where
+    # the process enabled x64 (the compiled fleet does)
+    with jax.enable_x64(False):
+        y, h = pl.pallas_call(
+            kernel,
+            grid=(B, di // block_di, num_chunks),
+            in_specs=[
+                pl.BlockSpec((1, chunk, block_di),
+                             lambda b, dk, ci: (b, ci, dk)),
+                pl.BlockSpec((1, chunk, block_di),
+                             lambda b, dk, ci: (b, ci, dk)),
+                pl.BlockSpec((1, chunk, N), lambda b, dk, ci: (b, ci, 0)),
+                pl.BlockSpec((1, chunk, N), lambda b, dk, ci: (b, ci, 0)),
+                pl.BlockSpec((block_di, N), lambda b, dk, ci: (dk, 0)),
+                pl.BlockSpec((block_di,), lambda b, dk, ci: (dk,)),
+                pl.BlockSpec((1, block_di, N), lambda b, dk, ci: (b, dk, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, chunk, block_di),
+                             lambda b, dk, ci: (b, ci, dk)),
+                pl.BlockSpec((1, block_di, N), lambda b, dk, ci: (b, dk, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, S, di), jnp.float32),
+                jax.ShapeDtypeStruct((B, di, N), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_di, N), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(u, dt, Bm, Cm, A, D, h0)
     return y, h

@@ -56,9 +56,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     compile_s = time.time() - t0
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        # older jaxlib returns one properties-dict per partition
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {k: getattr(mem, k) for k in

@@ -6,6 +6,13 @@ touches jax device state (the dry-run sets XLA_FLAGS before any jax call).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all `Auto`: JAX 0.9 defaults to `Explicit`,
+    under which `with_sharding_constraint` by logical names fails."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,11 +20,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     (pod,data,model) for 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over whatever local devices exist (tests)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

@@ -28,12 +28,15 @@ import numpy as np
 
 
 class RealExecutor:
-    """Wall-clock tier: reduced models, real logits, real latencies."""
+    """Wall-clock tier: real logits, real latencies on the local device.
+
+    `use_kernel=None` serves decode through the Pallas paged kernel on a
+    TPU and through the jnp reference elsewhere."""
 
     needs_tokens = True
 
     def __init__(self, cfg, params, *, num_pages: int, page_size: int,
-                 max_batch: int, qcfg=None, use_kernel: bool = False):
+                 max_batch: int, qcfg=None, use_kernel: Optional[bool] = None):
         import jax
         import jax.numpy as jnp
         from repro.serving.runner import init_pools, make_step_fns

@@ -221,6 +221,16 @@ def _compiled_phase(ilog_n: int, ilog_k: int, cap: int) -> Callable:
                 hi = jnp.where(act & ~le, mid, hi)
             return lo
 
+        def advance(t, target):
+            # numpy's `t + max(target - t, 1e-6)` as IEEE float64 rounds
+            # it. Where 0 < t <= target <= 2t the gap is exact
+            # (Sterbenz) and the sum lands on `target`; emulated float64
+            # (two f32 words on a TPU) need not, and a clock an ulp short
+            # of an arrival then takes a 1e-6 s nudge numpy never takes.
+            gap = target - t
+            exact = (gap >= 1e-6) & ((t == 0.0) | (target <= 2.0 * t))
+            return jnp.where(exact, target, t + jnp.maximum(gap, 1e-6))
+
         def cond(st):
             return st[1].any() & (st[0] < cap)
 
@@ -238,7 +248,7 @@ def _compiled_phase(ilog_n: int, ilog_k: int, cap: int) -> Callable:
             next_arr = take(r_arr, arrived)
             idle = (alive & (n_occ == 0) & (q_next == arrived)
                     & (arrived < n_req) & (next_arr > t))
-            t = jnp.where(idle, t + jnp.maximum(next_arr - t, 1e-6), t)
+            t = jnp.where(idle, advance(t, next_arr), t)
             hb = idle & (t >= horizon)
             live, alive = live & ~hb, alive & ~hb
             # 4. arrivals (np.searchsorted side="right"; inf padding).
@@ -332,7 +342,7 @@ def _compiled_phase(ilog_n: int, ilog_k: int, cap: int) -> Callable:
             # 9. no work: advance to the next arrival or flag a stall
             nw = alive & ~had_batch & ~dec
             pend = nw & (arrived < n_req)
-            t = jnp.where(pend, t + jnp.maximum(next_arr - t, 1e-6), t)
+            t = jnp.where(pend, advance(t, next_arr), t)
             stall = stall | (nw & ~pend & (q_next < arrived))
             live = live & ~(nw & ~pend)
             return (i + 1, live, stall, t, area, K, q_next, arrived,

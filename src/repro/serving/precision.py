@@ -29,7 +29,9 @@ bit of the committed stores (`tests/test_fleet_jit.py` pins this).
 """
 from __future__ import annotations
 
-from typing import Tuple
+import dataclasses
+import math
+from typing import List, Sequence, Tuple
 
 # documented jit-vs-numpy RunRecord agreement (rtol, atol); see module
 # docstring. The x64 bound absorbs FMA contraction over ~1e5-step clock
@@ -64,3 +66,27 @@ def jit_tolerance() -> Tuple[float, float]:
     """(rtol, atol) for jit-vs-numpy RunRecord comparisons under the
     currently active precision (call `enable_x64` first)."""
     return X64_TOLERANCE if active_x64() else F32_TOLERANCE
+
+
+def record_mismatches(oracle: Sequence, got: Sequence) -> List[tuple]:
+    """Fields where jit RunRecords leave the numpy oracle's: float fields
+    beyond `jit_tolerance()` (NaN must meet NaN), other fields unequal,
+    or a length mismatch. Empty means the records agree."""
+    if len(oracle) != len(got):
+        return [("n_records", len(oracle), len(got))]
+    rtol, atol = jit_tolerance()
+    bad = []
+    for a, b in zip(oracle, got):
+        da, db = dataclasses.asdict(a), dataclasses.asdict(b)
+        for key, va in da.items():
+            vb = db.get(key)
+            if isinstance(va, float):
+                if math.isnan(va):
+                    ok = isinstance(vb, float) and math.isnan(vb)
+                else:
+                    ok = abs(va - vb) <= rtol * abs(va) + atol
+            else:
+                ok = va == vb
+            if not ok:
+                bad.append((a.model, a.lam, key, va, vb))
+    return bad

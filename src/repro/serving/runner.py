@@ -3,7 +3,8 @@
 Fixed-shape, mask-driven step functions over `max_batch` slots:
   prefill_fn — process padded prompts for newly admitted slots, scatter K/V
                into their pages, emit the first sampled token (TTFT event).
-  decode_fn  — one token for every active slot via the paged-attention op.
+  decode_fn  — one token for every active slot via the paged-attention op
+               (the Pallas kernel on a TPU, the jnp reference elsewhere).
 
 SSM / xLSTM / hybrid blocks keep per-slot O(1) states in the same state
 pytree (they have no KV pages — the reason those archs run long_500k).
@@ -13,7 +14,7 @@ the dry-run covers their serve path).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +38,8 @@ def init_pools(cfg: ModelConfig, num_pages: int, page_size: int,
     pools: List[Dict[str, Any]] = []
     for kind, _ in model_lib.unit_pattern(cfg):
         if kind == "attn":
-            shape = (R, num_pages, page_size, cfg.num_kv_heads, hd)
+            # head-major pages: the paged kernel's block layout
+            shape = (R, cfg.num_kv_heads, num_pages, page_size, hd)
             pools.append({"k": jnp.zeros(shape, jnp.bfloat16),
                           "v": jnp.zeros(shape, jnp.bfloat16)})
         elif kind == "mamba":
@@ -69,7 +71,7 @@ def init_pools(cfg: ModelConfig, num_pages: int, page_size: int,
 
 def _scatter_kv(pool_k, pool_v, k, v, block_tables, positions, active,
                 page_size: int):
-    """Scatter per-token K/V into pages.
+    """Scatter per-token K/V into pages of a (Hkv, P, page, D) pool.
 
     k/v: (B, T, Hkv, D); positions: (B, T) absolute token positions;
     active: (B, T) bool — inactive writes land on trash page 0.
@@ -81,10 +83,10 @@ def _scatter_kv(pool_k, pool_v, k, v, block_tables, positions, active,
     pages = jnp.take_along_axis(block_tables, cols, axis=1)  # (B, T)
     pages = jnp.where(active, pages, 0)
     pf, of = pages.reshape(-1), offs.reshape(-1)
-    kf = k.reshape((-1,) + k.shape[2:])
-    vf = v.reshape((-1,) + v.shape[2:])
-    pool_k = pool_k.at[pf, of].set(kf.astype(pool_k.dtype))
-    pool_v = pool_v.at[pf, of].set(vf.astype(pool_v.dtype))
+    kf = k.reshape((-1,) + k.shape[2:]).swapaxes(0, 1)     # (Hkv, B*T, D)
+    vf = v.reshape((-1,) + v.shape[2:]).swapaxes(0, 1)
+    pool_k = pool_k.at[:, pf, of].set(kf.astype(pool_k.dtype))
+    pool_v = pool_v.at[:, pf, of].set(vf.astype(pool_v.dtype))
     return pool_k, pool_v
 
 
@@ -96,19 +98,21 @@ def _mask_state(new, old, active):
     return jax.tree.map(pick, new, old)
 
 
-def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
-                  use_kernel: bool = False):
-    """Build (prefill_fn, decode_fn) jitted closures for this config."""
+def make_decode_logits(cfg: ModelConfig, page_size: int, qcfg=None,
+                       use_kernel: Optional[bool] = None):
+    """One-token decode step, not jitted: `decode_logits(params, pools,
+    block_tables, seq_lens, tokens, active)` with tokens (B,) int32
+    returns (logits (B, V) f32, pools, seq_lens).
+
+    `use_kernel=None` takes the Pallas paged kernel on a TPU and the jnp
+    reference elsewhere."""
     if cfg.family == "encdec":
         raise NotImplementedError(
             "encoder-decoder serving uses the dry-run path only")
     pattern = model_lib.unit_pattern(cfg)
     hd = cfg.resolved_head_dim
 
-    # -- decode -------------------------------------------------------------
-    @jax.jit
-    def decode_fn(params, pools, block_tables, seq_lens, tokens, active):
-        """tokens: (B,) int32. Returns (next_tokens, pools, seq_lens)."""
+    def decode_logits(params, pools, block_tables, seq_lens, tokens, active):
         B = tokens.shape[0]
         x = model_lib.embed_tokens(params, cfg, tokens[:, None])
         positions = model_lib._positions(cfg, B, 1, offset=seq_lens)
@@ -156,8 +160,27 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
                                               tuple(pools)))
         x = apply_norm(params["final_norm"], x, cfg.norm_kind)
         logits = model_lib.unembed(params, cfg, x, qcfg)
-        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        return nxt, list(new_pools), seq_lens + active.astype(jnp.int32)
+        return (logits[:, 0], list(new_pools),
+                seq_lens + active.astype(jnp.int32))
+
+    return decode_logits
+
+
+def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
+                  use_kernel: Optional[bool] = None):
+    """Build (prefill_fn, decode_fn) jitted closures for this config
+    (`use_kernel` as in `make_decode_logits`)."""
+    decode_logits = make_decode_logits(cfg, page_size, qcfg, use_kernel)
+    pattern = model_lib.unit_pattern(cfg)
+    hd = cfg.resolved_head_dim
+
+    # -- decode -------------------------------------------------------------
+    @jax.jit
+    def decode_fn(params, pools, block_tables, seq_lens, tokens, active):
+        """tokens: (B,) int32. Returns (next_tokens, pools, seq_lens)."""
+        logits, pools, seq_lens = decode_logits(
+            params, pools, block_tables, seq_lens, tokens, active)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools, seq_lens
 
     # -- prefill ------------------------------------------------------------
     @functools.partial(jax.jit, static_argnames=())

@@ -49,6 +49,18 @@ V6E = HardwareGen("tpu-v6e", 918e12, 1836e12, 1640e9, 100e9, 32e9, 2.70,
 
 HW_BY_NAME = {h.name: h for h in (V5E, V5P, V6E)}
 
+# `jax.devices()[0].device_kind` -> generation: the live tier prices its
+# run by the chip it ran on ("TPU v5 lite" is what a v5e reports).
+HW_BY_DEVICE_KIND = {"TPU v5 lite": V5E, "TPU v5": V5P, "TPU v6 lite": V6E}
+
+
+def hw_for_device_kind(kind: str) -> HardwareGen:
+    """The generation (and price) of a device; an unknown kind raises."""
+    if kind not in HW_BY_DEVICE_KIND:
+        raise KeyError(f"no hardware entry for device kind {kind!r}; "
+                       f"known: {sorted(HW_BY_DEVICE_KIND)}")
+    return HW_BY_DEVICE_KIND[kind]
+
 # Pseudo-hardware entry for the CPU real-execution tier: throughput is
 # measured, only the price matters for C_eff shape validation.
 CPU_NODE = HardwareGen("cpu-node", 1e12, 1e12, 5e10, 1e9, 64e9, 1.00,

@@ -43,10 +43,13 @@ def test_flash_attention(B, Sq, Sk, Hq, Hkv, D, causal, dtype):
     (3, 8, 1, 128, 16, 3, 64),
     (1, 4, 4, 64, 8, 6, 12),
     (4, 8, 2, 64, 8, 5, 64),
+    (2, 16, 8, 64, 16, 4, 32),           # granite-moe-1b-a400m widths
 ])
 def test_paged_attention(B, Hq, Hkv, D, page, maxp, P):
+    """Head-major pools (Hkv, P, page, D): the kernel in interpret mode
+    against the gather-then-dense reference."""
     q = _randn((B, Hq, D))
-    kp, vp = _randn((P, page, Hkv, D)), _randn((P, page, Hkv, D))
+    kp, vp = _randn((Hkv, P, page, D)), _randn((Hkv, P, page, D))
     bt = jnp.asarray(RNG.choice(P, size=(B, maxp),
                                 replace=B * maxp > P), jnp.int32)
     sl = jnp.asarray(RNG.integers(1, page * maxp + 1, size=(B,)), jnp.int32)

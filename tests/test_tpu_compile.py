@@ -1,0 +1,110 @@
+"""Compile the live tier's kernels and the granite decode step for a
+described TPU v5e, with no chip attached. Mosaic and XLA:TPU refuse here
+what they would refuse on the chip (misaligned blocks, VMEM overuse), so
+these guard the served path at no chip time. Nothing runs.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and the test workers import every
+test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:             # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    """ShapeDtypeStructs of `tree` placed on the described chip."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,D,P,page,maxp", [
+    (32, 16, 8, 64, 4096, 16, 64),       # granite-moe-1b-a400m
+    (8, 32, 8, 128, 1024, 16, 32),       # a D-128 GQA width
+])
+def test_paged_attention_kernel_compiles(one_chip, B, Hq, Hkv, D, P, page,
+                                         maxp):
+    from repro.kernels.paged_attention.kernel import paged_attention_kernel
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((Hkv, P, page, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((Hkv, P, page, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((B, maxp), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32)))
+    compiled = paged_attention_kernel.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_kernel_compiles(one_chip):
+    from repro.kernels.flash_attention.kernel import flash_attention_kernel
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((2, 16, 512, 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 8, 512, 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 8, 512, 64), jnp.bfloat16)))
+    compiled = flash_attention_kernel.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kernels_compile_with_x64_enabled(one_chip):
+    """The compiled fleet turns x64 on process-wide; Mosaic has no 64-bit
+    types, so the kernels must still trace in 32-bit mode after that."""
+    from repro.kernels.flash_attention.kernel import flash_attention_kernel
+    from repro.kernels.paged_attention.kernel import paged_attention_kernel
+    bf16 = jnp.bfloat16
+    paged = _on(one_chip, (
+        jax.ShapeDtypeStruct((32, 16, 64), bf16),
+        jax.ShapeDtypeStruct((8, 4096, 16, 64), bf16),
+        jax.ShapeDtypeStruct((8, 4096, 16, 64), bf16),
+        jax.ShapeDtypeStruct((32, 64), jnp.int32),
+        jax.ShapeDtypeStruct((32,), jnp.int32)))
+    flash = _on(one_chip, (
+        jax.ShapeDtypeStruct((2, 16, 512, 64), bf16),
+        jax.ShapeDtypeStruct((2, 8, 512, 64), bf16),
+        jax.ShapeDtypeStruct((2, 8, 512, 64), bf16)))
+    with jax.enable_x64(True):
+        for kernel, args in ((paged_attention_kernel, paged),
+                             (flash_attention_kernel, flash)):
+            compiled = kernel.lower(*args).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_granite_decode_step_compiles_with_paged_kernel(one_chip):
+    """The whole served decode step at granite's published widths and the
+    live tier's sizes (launch/serve.py TIER_MAX_BATCH slots, 64 pages
+    each plus the trash page, as `build_engine` sizes the pool)."""
+    from repro.configs import get_config
+    from repro.launch.serve import PAGE_SIZE, TIER_MAX_BATCH
+    from repro.models import init_params
+    from repro.serving.runner import init_pools, make_step_fns
+
+    cfg = get_config("granite-moe-1b-a400m")
+    max_batch, page, maxp = TIER_MAX_BATCH["real"], PAGE_SIZE, 64
+    num_pages = max_batch * maxp + 1
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    pools = jax.eval_shape(
+        lambda: init_pools(cfg, num_pages, page, max_batch))
+    args = _on(one_chip, (
+        params, pools,
+        jax.ShapeDtypeStruct((max_batch, maxp), jnp.int32),
+        jax.ShapeDtypeStruct((max_batch,), jnp.int32),
+        jax.ShapeDtypeStruct((max_batch,), jnp.int32),
+        jax.ShapeDtypeStruct((max_batch,), jnp.bool_)))
+    _, decode_fn = make_step_fns(cfg, page, use_kernel=True)
+    compiled = decode_fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
