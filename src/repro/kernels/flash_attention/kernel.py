@@ -93,6 +93,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
     with jax.enable_x64(False):
         return pl.pallas_call(
             kernel,
+            name="flash_attention",
             grid=(B, Hq, num_qb, num_kb),
             in_specs=[
                 pl.BlockSpec((1, 1, block_q, D),

@@ -56,6 +56,7 @@ def int8_matmul_kernel(x_q, w_q, x_scale, w_scale, *, block_m: int = 256,
     with jax.enable_x64(False):
         return pl.pallas_call(
             kernel,
+            name="int8_matmul",
             grid=(M // block_m, N // block_n, num_kb),
             in_specs=[
                 pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),

@@ -108,6 +108,7 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, seq_lens, *,
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
+            name="paged_attention",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
             compiler_params=pltpu.CompilerParams(

@@ -69,6 +69,7 @@ def ssm_scan_kernel(u, dt, Bm, Cm, A, D, h0, *, chunk: int = 256,
     with jax.enable_x64(False):
         y, h = pl.pallas_call(
             kernel,
+            name="ssm_scan",
             grid=(B, di // block_di, num_chunks),
             in_specs=[
                 pl.BlockSpec((1, chunk, block_di),

@@ -15,11 +15,16 @@ REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 def use_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a fixed directory and
     return it. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it
-    itself and nothing is set here; otherwise the cache is
-    `<repo>/.jax_cache`, a fixed path so that later runs find it."""
+    itself and no directory is set here; otherwise the cache is
+    `<repo>/.jax_cache`, a fixed path so that later runs find it.
+
+    The cache key keeps the programs' metadata: JAX strips it by default,
+    and an executable cached from a build without the named scopes would
+    then be found again, and profile without them."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
     return str(REPO_CACHE_DIR)

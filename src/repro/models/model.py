@@ -219,12 +219,15 @@ def _apply_attn_block(p, cfg: ModelConfig, x, positions, qcfg,
 def _apply_ff(p, cfg: ModelConfig, x, is_moe: bool, qcfg):
     aux = jnp.zeros((), jnp.float32)
     if is_moe and "moe" in p:
-        h = apply_norm(p["ln2"], x, cfg.norm_kind)
-        mo, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe, cfg.mlp_kind, qcfg)
-        x = x + mo
+        with jax.named_scope("moe"):
+            h = apply_norm(p["ln2"], x, cfg.norm_kind)
+            mo, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe, cfg.mlp_kind,
+                                        qcfg)
+            x = x + mo
     elif "mlp" in p:
-        h = apply_norm(p["ln2"], x, cfg.norm_kind)
-        x = x + apply_mlp(p["mlp"], h, cfg.mlp_kind, qcfg)
+        with jax.named_scope("mlp"):
+            h = apply_norm(p["ln2"], x, cfg.norm_kind)
+            x = x + apply_mlp(p["mlp"], h, cfg.mlp_kind, qcfg)
     return x, aux
 
 

@@ -64,7 +64,9 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
 
     Dispatch pipeline: group tokens -> route top-k -> positional cumsum for
     capacity -> one-hot dispatch einsum -> expert MLPs (batched over E) ->
-    combine einsum weighted by gate probs.
+    combine einsum weighted by gate probs. Each stage runs under its
+    named scope (`route`, `dispatch`, `experts`, `combine`), so a profile
+    attributes device time to it.
     """
     B, S, d = x.shape
     E, K = cfg.num_experts, cfg.top_k
@@ -75,50 +77,60 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
     cap = max(K, int(math.ceil(Sg * K / E * cfg.capacity_factor)))
 
     xg = x.reshape(G, Sg, d)
-    logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)                    # (G,Sg,E)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)              # (G,Sg,K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    with jax.named_scope("route"):
+        logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32),
+                            p["router"])
+        probs = jax.nn.softmax(logits, axis=-1)                # (G,Sg,E)
+        gate_vals, gate_idx = jax.lax.top_k(probs, K)          # (G,Sg,K)
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
 
-    # Load-balancing aux loss (Switch-style): E * mean(frac_tokens * mean_prob)
-    frac = jnp.mean(
-        jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32), axis=(0, 1))
-    aux = E * jnp.sum(frac * jnp.mean(probs, axis=(0, 1)))
+        # Load-balancing aux loss (Switch-style):
+        # E * mean(frac_tokens * mean_prob)
+        frac = jnp.mean(
+            jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32),
+            axis=(0, 1))
+        aux = E * jnp.sum(frac * jnp.mean(probs, axis=(0, 1)))
 
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)    # (G,Sg,K,E)
-    # position of each (token, k) assignment within its expert's queue
-    flat = onehot.reshape(G, Sg * K, E)
-    pos = jnp.cumsum(flat, axis=1) - 1.0                       # (G,Sg*K,E)
-    pos = pos.reshape(G, Sg, K, E)
-    keep = (pos < cap) & (onehot > 0)
-    pos_c = jnp.clip(pos, 0, cap - 1).astype(jnp.int32)
-    cap_oh = jax.nn.one_hot(pos_c, cap, dtype=jnp.float32)     # (G,Sg,K,E,C)
-    dispatch = jnp.where(keep[..., None], cap_oh, 0.0)         # (G,Sg,K,E,C)
-    combine = dispatch * gate_vals[..., None, None]
-    dispatch_t = jnp.sum(dispatch, axis=2)                     # (G,Sg,E,C)
-    combine_t = jnp.sum(combine, axis=2)
+        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (G,Sg,K,E)
+        # position of each (token, k) assignment within its expert's queue
+        flat = onehot.reshape(G, Sg * K, E)
+        pos = jnp.cumsum(flat, axis=1) - 1.0                   # (G,Sg*K,E)
+        pos = pos.reshape(G, Sg, K, E)
+        keep = (pos < cap) & (onehot > 0)
+        pos_c = jnp.clip(pos, 0, cap - 1).astype(jnp.int32)
+        cap_oh = jax.nn.one_hot(pos_c, cap, dtype=jnp.float32)  # (G,Sg,K,E,C)
+        dispatch = jnp.where(keep[..., None], cap_oh, 0.0)     # (G,Sg,K,E,C)
+        combine = dispatch * gate_vals[..., None, None]
+        dispatch_t = jnp.sum(dispatch, axis=2)                 # (G,Sg,E,C)
+        combine_t = jnp.sum(combine, axis=2)
 
     cdt = x.dtype
-    expert_in = jnp.einsum("gsec,gsd->egcd", dispatch_t.astype(cdt), xg)
-    # e over EP where divisible AND g keeps the batch axes: when the expert
-    # count doesn't divide the model axis (mixtral: 8 on 16) the e-spec
-    # drops but g-sharding prevents GSPMD replicating a multi-GB tensor
-    # (observed: 5.7 TB/step of all-reduce before this constraint carried
-    # the batch dim — §Perf log, mixtral train baseline-fix)
-    expert_in = constrain(expert_in, "experts", "batch", None, None)
+    with jax.named_scope("dispatch"):
+        expert_in = jnp.einsum("gsec,gsd->egcd", dispatch_t.astype(cdt), xg)
+        # e over EP where divisible AND g keeps the batch axes: when the
+        # expert count doesn't divide the model axis (mixtral: 8 on 16) the
+        # e-spec drops but g-sharding prevents GSPMD replicating a multi-GB
+        # tensor (observed: 5.7 TB/step of all-reduce before this
+        # constraint carried the batch dim — §Perf log, mixtral train
+        # baseline-fix)
+        expert_in = constrain(expert_in, "experts", "batch", None, None)
 
-    up = jnp.einsum("egcd,edf->egcf", expert_in, _w(p["experts_up"], cdt))
-    gatep = p.get("experts_gate")
-    gate_h = (jnp.einsum("egcd,edf->egcf", expert_in, _w(gatep, cdt))
-              if gatep is not None else None)
-    h = _activate(up, gate_h, mlp_kind)
-    out_e = jnp.einsum("egcf,efd->egcd", h, _w(p["experts_down"], cdt))
-    out_e = constrain(out_e, "experts", "batch", None, None)
+    with jax.named_scope("experts"):
+        up = jnp.einsum("egcd,edf->egcf", expert_in,
+                        _w(p["experts_up"], cdt))
+        gatep = p.get("experts_gate")
+        gate_h = (jnp.einsum("egcd,edf->egcf", expert_in, _w(gatep, cdt))
+                  if gatep is not None else None)
+        h = _activate(up, gate_h, mlp_kind)
+        out_e = jnp.einsum("egcf,efd->egcd", h, _w(p["experts_down"], cdt))
+        out_e = constrain(out_e, "experts", "batch", None, None)
 
-    out = jnp.einsum("gsec,egcd->gsd", combine_t.astype(cdt), out_e)
-    out = out.reshape(B, S, d)
+    with jax.named_scope("combine"):
+        out = jnp.einsum("gsec,egcd->gsd", combine_t.astype(cdt), out_e)
+        out = out.reshape(B, S, d)
     if "shared" in p:
         from repro.models.layers import apply_mlp
-        out = out + apply_mlp(p["shared"], x, mlp_kind, qcfg)
+        with jax.named_scope("shared"):
+            out = out + apply_mlp(p["shared"], x, mlp_kind, qcfg)
     return out, aux

@@ -50,6 +50,7 @@ from repro.serving.overload import OverloadPolicy
 from repro.serving.request import Request, RequestState
 from repro.serving.resilience import (FailureSpec, FailureTimeline,
                                       RetryPolicy, as_failure_events)
+from repro.serving.tracing import span
 
 
 @dataclasses.dataclass
@@ -369,13 +370,24 @@ class Engine:
             self.slot_req[slot] = req
             batch.append(req)
             budget -= req.prompt_len
-            self.metrics.set("repro:kv_cache_usage_perc",
-                             self.pm.utilization())
         return batch
+
+    def _prefill_inputs(self, batch: List[Request]
+                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The prefill's token matrix, prompt lengths and row mask."""
+        B = self.cfg.max_batch
+        lens = np.zeros(B, np.int32)
+        mask = np.zeros(B, bool)
+        for r in batch:
+            lens[r.slot] = r.prompt_len
+            mask[r.slot] = True
+        return self._prefill_tokens(batch), lens, mask
 
     def _prefill_tokens(self, batch: List[Request]) -> np.ndarray:
         """Materialise the padded token matrix (only if the executor reads
-        token values; the sim tier meters counts and timing only)."""
+        token values; the sim tier meters counts and timing only). Every
+        row is computed at the matrix's width: its size is what
+        `repro:prefill_tokens_computed_total` counts."""
         B = self.cfg.max_batch
         if not getattr(self.ex, "needs_tokens", True):
             return np.zeros((B, 0), np.int32)
@@ -393,7 +405,6 @@ class Engine:
     def _run_fast(self, requests: Sequence[Request], *,
                   horizon: Optional[float] = None,
                   timeline: Optional[FailureTimeline] = None) -> List[Request]:
-        B = self.cfg.max_batch
         pending = sorted(
             (r for r in requests
              if r.state == RequestState.QUEUED and r.slot < 0
@@ -452,44 +463,44 @@ class Engine:
                                 next_ev.time + next_ev.downtime)
                         timeline.pop()
                         next_ev = timeline.peek()
-            # arrivals (client re-submissions are arrivals too)
-            pi = self._drain_submissions(queue, pending, pi)
-            if self._requeue:
-                queue.extendleft(reversed(self._requeue))
-                self._requeue = []
-
-            blocked = self.t < self._down_until   # restart/warmup lag
-            batch = [] if blocked else self._admit_from(queue)
+            with span("engine.admit"):
+                # arrivals (client re-submissions are arrivals too)
+                pi = self._drain_submissions(queue, pending, pi)
+                if self._requeue:
+                    queue.extendleft(reversed(self._requeue))
+                    self._requeue = []
+                blocked = self.t < self._down_until   # restart/warmup lag
+                batch = [] if blocked else self._admit_from(queue)
+                if batch:
+                    tokens, lens, mask = self._prefill_inputs(batch)
             did_work = False
             if batch:
-                lens = np.zeros(B, np.int32)
-                mask = np.zeros(B, bool)
-                for r in batch:
-                    lens[r.slot] = r.prompt_len
-                    mask[r.slot] = True
-                first, dt = self.ex.prefill(self._prefill_tokens(batch),
-                                            lens, mask,
+                first, dt = self.ex.prefill(tokens, lens, mask,
                                             self.pm.block_tables)
-                self._advance(dt)
-                n_prompt = 0
-                for r in batch:
-                    r.state = RequestState.RUNNING
-                    r.tokens_out = 1
-                    r.first_token_time = self.t
-                    r.prev_token_time = self.t
-                    self.slot_tokens[r.slot] = first[r.slot]
-                    self.context_lens[r.slot] = r.prompt_len
-                    self.active[r.slot] = True
-                    self.tokens_out_arr[r.slot] = 1
-                    self.max_new_arr[r.slot] = r.max_new_tokens
-                    n_prompt += r.prompt_len
-                self.metrics.inc("repro:prompt_tokens_total", n_prompt)
-                self.metrics.inc("repro:generation_tokens_total", len(batch))
-                self._observe_ttfts(batch)
-                for r in batch:
-                    if self.slot_tokens[r.slot] >= 0 and \
-                            r.tokens_out >= r.max_new_tokens:
-                        self._complete(r.slot)
+                with span("engine.update"):
+                    self._advance(dt)
+                    n_prompt = 0
+                    for r in batch:
+                        r.state = RequestState.RUNNING
+                        r.tokens_out = 1
+                        r.first_token_time = self.t
+                        r.prev_token_time = self.t
+                        self.slot_tokens[r.slot] = first[r.slot]
+                        self.context_lens[r.slot] = r.prompt_len
+                        self.active[r.slot] = True
+                        self.tokens_out_arr[r.slot] = 1
+                        self.max_new_arr[r.slot] = r.max_new_tokens
+                        n_prompt += r.prompt_len
+                    self.metrics.inc("repro:prompt_tokens_total", n_prompt)
+                    self.metrics.inc("repro:prefill_tokens_computed_total",
+                                     tokens.size)
+                    self.metrics.inc("repro:generation_tokens_total",
+                                     len(batch))
+                    self._observe_ttfts(batch)
+                    for r in batch:
+                        if self.slot_tokens[r.slot] >= 0 and \
+                                r.tokens_out >= r.max_new_tokens:
+                            self._complete(r.slot)
                 did_work = True
 
             # ---- decode: closed-form jump to the next scheduling event
@@ -523,25 +534,27 @@ class Engine:
                 nxt, dt, steps = self.ex.decode_multi(
                     self.slot_tokens, self.active, self.pm.block_tables,
                     self.context_lens, k_max, tbudget)
-                self._advance(dt)
-                self.n_decode_steps += steps
-                if steps > 1:
-                    self.n_ff_jumps += 1
-                act = self.active
-                if needs_tok:
-                    self.slot_tokens[act] = nxt[act]
-                self.tokens_out_arr[act] += steps
-                self.context_lens[act] += steps
-                self.metrics.inc("repro:generation_tokens_total",
-                                 steps * nrun)
-                done_mask = act & (self.tokens_out_arr >= self.max_new_arr)
-                if done_mask.any():
-                    for slot in np.flatnonzero(done_mask):
-                        slot = int(slot)
-                        r = self.slot_req[slot]
-                        r.tokens_out = int(self.tokens_out_arr[slot])
-                        r.prev_token_time = self.t
-                        self._complete(slot)
+                with span("engine.update"):
+                    self._advance(dt)
+                    self.n_decode_steps += steps
+                    if steps > 1:
+                        self.n_ff_jumps += 1
+                    act = self.active
+                    if needs_tok:
+                        self.slot_tokens[act] = nxt[act]
+                    self.tokens_out_arr[act] += steps
+                    self.context_lens[act] += steps
+                    self.metrics.inc("repro:generation_tokens_total",
+                                     steps * nrun)
+                    done_mask = act & (self.tokens_out_arr >=
+                                       self.max_new_arr)
+                    if done_mask.any():
+                        for slot in np.flatnonzero(done_mask):
+                            slot = int(slot)
+                            r = self.slot_req[slot]
+                            r.tokens_out = int(self.tokens_out_arr[slot])
+                            r.prev_token_time = self.t
+                            self._complete(slot)
                 did_work = True
 
             if not did_work:
@@ -598,39 +611,37 @@ class Engine:
                                            next_ev.time + next_ev.downtime)
                 timeline.pop()
                 next_ev = timeline.peek()
-            # arrivals (client re-submissions are arrivals too)
-            pi = self._drain_submissions(queue, pending, pi)
-            queue = self._requeue + queue
-            self._requeue = []
-
-            blocked = self.t < self._down_until   # restart/warmup lag
-            batch = [] if blocked else self._admit_from(queue)
+            with span("engine.admit"):
+                # arrivals (client re-submissions are arrivals too)
+                pi = self._drain_submissions(queue, pending, pi)
+                queue = self._requeue + queue
+                self._requeue = []
+                blocked = self.t < self._down_until   # restart/warmup lag
+                batch = [] if blocked else self._admit_from(queue)
+                if batch:
+                    tokens, lens, mask = self._prefill_inputs(batch)
             did_work = False
             if batch:
-                B = self.cfg.max_batch
-                tokens = self._prefill_tokens(batch)
-                lens = np.zeros(B, np.int32)
-                mask = np.zeros(B, bool)
-                for r in batch:
-                    lens[r.slot] = r.prompt_len
-                    mask[r.slot] = True
                 first, dt = self.ex.prefill(tokens, lens, mask,
                                             self.pm.block_tables)
-                self._advance(dt)
-                for r in batch:
-                    r.state = RequestState.RUNNING
-                    r.tokens_out = 1
-                    r.first_token_time = self.t
-                    r.prev_token_time = self.t
-                    self.slot_tokens[r.slot] = first[r.slot]
-                    self.context_lens[r.slot] = r.prompt_len
-                    self.metrics.inc("repro:prompt_tokens_total",
-                                     r.prompt_len)
-                    self.metrics.inc("repro:generation_tokens_total", 1)
-                    if self.slot_tokens[r.slot] >= 0 and \
-                            r.tokens_out >= r.max_new_tokens:
-                        self._complete(r.slot)
-                self._observe_ttfts(batch)
+                with span("engine.update"):
+                    self._advance(dt)
+                    self.metrics.inc("repro:prefill_tokens_computed_total",
+                                     tokens.size)
+                    for r in batch:
+                        r.state = RequestState.RUNNING
+                        r.tokens_out = 1
+                        r.first_token_time = self.t
+                        r.prev_token_time = self.t
+                        self.slot_tokens[r.slot] = first[r.slot]
+                        self.context_lens[r.slot] = r.prompt_len
+                        self.metrics.inc("repro:prompt_tokens_total",
+                                         r.prompt_len)
+                        self.metrics.inc("repro:generation_tokens_total", 1)
+                        if self.slot_tokens[r.slot] >= 0 and \
+                                r.tokens_out >= r.max_new_tokens:
+                            self._complete(r.slot)
+                    self._observe_ttfts(batch)
                 did_work = True
 
             # ---- decode step for all running slots
@@ -648,18 +659,18 @@ class Engine:
                 except TypeError:
                     nxt, dt = self.ex.decode(self.slot_tokens, active,
                                              self.pm.block_tables)
-                self._advance(dt)
-                self.n_decode_steps += 1
-                ngen = 0
-                for r in running:
-                    r.tokens_out += 1
-                    ngen += 1
-                    r.prev_token_time = self.t
-                    self.slot_tokens[r.slot] = nxt[r.slot]
-                    self.context_lens[r.slot] += 1
-                    if r.tokens_out >= r.max_new_tokens:
-                        self._complete(r.slot)
-                self.metrics.inc("repro:generation_tokens_total", ngen)
+                with span("engine.update"):
+                    self._advance(dt)
+                    self.n_decode_steps += 1
+                    for r in running:
+                        r.tokens_out += 1
+                        r.prev_token_time = self.t
+                        self.slot_tokens[r.slot] = nxt[r.slot]
+                        self.context_lens[r.slot] += 1
+                        if r.tokens_out >= r.max_new_tokens:
+                            self._complete(r.slot)
+                    self.metrics.inc("repro:generation_tokens_total",
+                                     len(running))
                 did_work = True
 
             if not did_work:

@@ -26,6 +26,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.serving.tracing import span
+
 
 class RealExecutor:
     """Wall-clock tier: real logits, real latencies on the local device.
@@ -58,11 +60,16 @@ class RealExecutor:
         import time
         jax, jnp = self._jax, self._jnp
         t0 = time.perf_counter()
-        first, self.pools, self.seq_lens = self.prefill_fn(
-            self.params, self.pools, jnp.asarray(block_tables),
-            self.seq_lens, jnp.asarray(tokens), jnp.asarray(lens),
-            jnp.asarray(do_mask))
-        first = np.asarray(jax.block_until_ready(first))
+        with span("executor.prefill.inputs"):
+            bt, tokens, lens, do_mask = (
+                jnp.asarray(block_tables), jnp.asarray(tokens),
+                jnp.asarray(lens), jnp.asarray(do_mask))
+        with span("executor.prefill.launch"):
+            first, self.pools, self.seq_lens = self.prefill_fn(
+                self.params, self.pools, bt, self.seq_lens, tokens, lens,
+                do_mask)
+        with span("executor.prefill.wait"):
+            first = np.asarray(jax.block_until_ready(first))
         return first, time.perf_counter() - t0
 
     def decode(self, tokens: np.ndarray, active: np.ndarray,
@@ -70,10 +77,14 @@ class RealExecutor:
         import time
         jax, jnp = self._jax, self._jnp
         t0 = time.perf_counter()
-        nxt, self.pools, self.seq_lens = self.decode_fn(
-            self.params, self.pools, jnp.asarray(block_tables),
-            self.seq_lens, jnp.asarray(tokens), jnp.asarray(active))
-        nxt = np.asarray(jax.block_until_ready(nxt))
+        with span("executor.decode.inputs"):
+            bt, tokens, active = (jnp.asarray(block_tables),
+                                  jnp.asarray(tokens), jnp.asarray(active))
+        with span("executor.decode.launch"):
+            nxt, self.pools, self.seq_lens = self.decode_fn(
+                self.params, self.pools, bt, self.seq_lens, tokens, active)
+        with span("executor.decode.wait"):
+            nxt = np.asarray(jax.block_until_ready(nxt))
         return nxt, time.perf_counter() - t0
 
     def decode_multi(self, tokens: np.ndarray, active: np.ndarray,

@@ -5,6 +5,9 @@ Fixed-shape, mask-driven step functions over `max_batch` slots:
                into their pages, emit the first sampled token (TTFT event).
   decode_fn  — one token for every active slot via the paged-attention op
                (the Pallas kernel on a TPU, the jnp reference elsewhere).
+Named scopes tile both steps (`embed`; `attention`, with its K/V write in
+`attention/kv_write`; `moe` or `mlp`; `unembed`), so a profile can
+attribute device time to them; the names are what benchmarks read.
 
 SSM / xLSTM / hybrid blocks keep per-slot O(1) states in the same state
 pytree (they have no KV pages — the reason those archs run long_500k).
@@ -114,8 +117,9 @@ def make_decode_logits(cfg: ModelConfig, page_size: int, qcfg=None,
 
     def decode_logits(params, pools, block_tables, seq_lens, tokens, active):
         B = tokens.shape[0]
-        x = model_lib.embed_tokens(params, cfg, tokens[:, None])
-        positions = model_lib._positions(cfg, B, 1, offset=seq_lens)
+        with jax.named_scope("embed"):
+            x = model_lib.embed_tokens(params, cfg, tokens[:, None])
+            positions = model_lib._positions(cfg, B, 1, offset=seq_lens)
 
         def body(x, xs):
             stacked_p, pools_r = xs
@@ -123,43 +127,48 @@ def make_decode_logits(cfg: ModelConfig, page_size: int, qcfg=None,
             for j, (kind, is_moe) in enumerate(pattern):
                 p, pool = stacked_p[j], pools_r[j]
                 if kind == "attn":
-                    h = apply_norm(p["ln1"], x, cfg.norm_kind)
-                    q, k, v = attn_lib.qkv(p["attn"], h, cfg.num_heads,
-                                           cfg.num_kv_heads, hd, qcfg)
-                    q = attn_lib.rotate(cfg.rope_kind, q, positions,
-                                        cfg.rope_theta)
-                    k = attn_lib.rotate(cfg.rope_kind, k, positions,
-                                        cfg.rope_theta)
-                    pk, pv = _scatter_kv(
-                        pool["k"], pool["v"], k, v, block_tables,
-                        seq_lens[:, None], active[:, None], page_size)
-                    o = paged_attention(
-                        q[:, 0], pk.astype(x.dtype), pv.astype(x.dtype),
-                        block_tables, seq_lens + active.astype(jnp.int32),
-                        use_kernel=use_kernel)
-                    x = x + linear(o.reshape(B, 1, cfg.num_heads * hd),
-                                   p["attn"]["wo"], qcfg)
+                    with jax.named_scope("attention"):
+                        h = apply_norm(p["ln1"], x, cfg.norm_kind)
+                        q, k, v = attn_lib.qkv(p["attn"], h, cfg.num_heads,
+                                               cfg.num_kv_heads, hd, qcfg)
+                        q = attn_lib.rotate(cfg.rope_kind, q, positions,
+                                            cfg.rope_theta)
+                        k = attn_lib.rotate(cfg.rope_kind, k, positions,
+                                            cfg.rope_theta)
+                        with jax.named_scope("kv_write"):
+                            pk, pv = _scatter_kv(
+                                pool["k"], pool["v"], k, v, block_tables,
+                                seq_lens[:, None], active[:, None],
+                                page_size)
+                        o = paged_attention(
+                            q[:, 0], pk.astype(x.dtype), pv.astype(x.dtype),
+                            block_tables, seq_lens + active.astype(jnp.int32),
+                            use_kernel=use_kernel)
+                        x = x + linear(o.reshape(B, 1, cfg.num_heads * hd),
+                                       p["attn"]["wo"], qcfg)
                     new_pools.append({"k": pk, "v": pv})
                 else:
-                    h = apply_norm(p["ln1"], x, cfg.norm_kind)
-                    if kind == "mamba":
-                        y, st = ssm_lib.mamba_decode_step(
-                            p["mamba"], h, pool, cfg.ssm, qcfg)
-                    elif kind == "mlstm":
-                        y, st = xlstm_lib.mlstm_seq(
-                            p, h, cfg.num_heads, cfg.xlstm, pool, qcfg)
-                    else:
-                        y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm, pool,
-                                                    qcfg)
-                    x = x + y
-                    new_pools.append(_mask_state(st, pool, active))
+                    with jax.named_scope(kind):
+                        h = apply_norm(p["ln1"], x, cfg.norm_kind)
+                        if kind == "mamba":
+                            y, st = ssm_lib.mamba_decode_step(
+                                p["mamba"], h, pool, cfg.ssm, qcfg)
+                        elif kind == "mlstm":
+                            y, st = xlstm_lib.mlstm_seq(
+                                p, h, cfg.num_heads, cfg.xlstm, pool, qcfg)
+                        else:
+                            y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm,
+                                                        pool, qcfg)
+                        x = x + y
+                        new_pools.append(_mask_state(st, pool, active))
                 x, _ = model_lib._apply_ff(p, cfg, x, is_moe, qcfg)
             return x, tuple(new_pools)
 
         x, new_pools = jax.lax.scan(body, x, (params["blocks"],
                                               tuple(pools)))
-        x = apply_norm(params["final_norm"], x, cfg.norm_kind)
-        logits = model_lib.unembed(params, cfg, x, qcfg)
+        with jax.named_scope("unembed"):
+            x = apply_norm(params["final_norm"], x, cfg.norm_kind)
+            logits = model_lib.unembed(params, cfg, x, qcfg)
         return (logits[:, 0], list(new_pools),
                 seq_lens + active.astype(jnp.int32))
 
@@ -180,7 +189,9 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
         """tokens: (B,) int32. Returns (next_tokens, pools, seq_lens)."""
         logits, pools, seq_lens = decode_logits(
             params, pools, block_tables, seq_lens, tokens, active)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools, seq_lens
+        with jax.named_scope("unembed"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return nxt, pools, seq_lens
 
     # -- prefill ------------------------------------------------------------
     @functools.partial(jax.jit, static_argnames=())
@@ -192,10 +203,11 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
         seq_lens) with seq_lens set to lens for those slots.
         """
         B, Lp = tokens.shape
-        x = model_lib.embed_tokens(params, cfg, tokens)
-        positions = model_lib._positions(cfg, B, Lp)
-        tok_active = (jnp.arange(Lp)[None] < lens[:, None]) & \
-            do_prefill[:, None]
+        with jax.named_scope("embed"):
+            x = model_lib.embed_tokens(params, cfg, tokens)
+            positions = model_lib._positions(cfg, B, Lp)
+            tok_active = (jnp.arange(Lp)[None] < lens[:, None]) & \
+                do_prefill[:, None]
 
         def body(x, xs):
             stacked_p, pools_r = xs
@@ -203,48 +215,53 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
             for j, (kind, is_moe) in enumerate(pattern):
                 p, pool = stacked_p[j], pools_r[j]
                 if kind == "attn":
-                    h = apply_norm(p["ln1"], x, cfg.norm_kind)
-                    q, k, v = attn_lib.qkv(p["attn"], h, cfg.num_heads,
-                                           cfg.num_kv_heads, hd, qcfg)
-                    pos2 = model_lib._positions(cfg, B, Lp)
-                    q = attn_lib.rotate(cfg.rope_kind, q, pos2,
-                                        cfg.rope_theta)
-                    k = attn_lib.rotate(cfg.rope_kind, k, pos2,
-                                        cfg.rope_theta)
-                    o = attn_lib.causal_attention(q, k, v, kv_len=lens)
-                    x = x + linear(o.reshape(B, Lp, cfg.num_heads * hd),
-                                   p["attn"]["wo"], qcfg)
-                    posmat = jnp.broadcast_to(jnp.arange(Lp)[None], (B, Lp))
-                    pk, pv = _scatter_kv(pool["k"], pool["v"], k, v,
-                                         block_tables, posmat, tok_active,
-                                         page_size)
+                    with jax.named_scope("attention"):
+                        h = apply_norm(p["ln1"], x, cfg.norm_kind)
+                        q, k, v = attn_lib.qkv(p["attn"], h, cfg.num_heads,
+                                               cfg.num_kv_heads, hd, qcfg)
+                        pos2 = model_lib._positions(cfg, B, Lp)
+                        q = attn_lib.rotate(cfg.rope_kind, q, pos2,
+                                            cfg.rope_theta)
+                        k = attn_lib.rotate(cfg.rope_kind, k, pos2,
+                                            cfg.rope_theta)
+                        o = attn_lib.causal_attention(q, k, v, kv_len=lens)
+                        x = x + linear(o.reshape(B, Lp, cfg.num_heads * hd),
+                                       p["attn"]["wo"], qcfg)
+                        with jax.named_scope("kv_write"):
+                            posmat = jnp.broadcast_to(jnp.arange(Lp)[None],
+                                                      (B, Lp))
+                            pk, pv = _scatter_kv(pool["k"], pool["v"], k, v,
+                                                 block_tables, posmat,
+                                                 tok_active, page_size)
                     new_pools.append({"k": pk, "v": pv})
                 else:
-                    h = apply_norm(p["ln1"], x, cfg.norm_kind)
-                    if kind == "mamba":
-                        y, st = ssm_lib.apply_mamba(p["mamba"], h, cfg.ssm,
-                                                    qcfg)
-                    elif kind == "mlstm":
-                        y, st = xlstm_lib.mlstm_seq(
-                            p, h, cfg.num_heads, cfg.xlstm, None, qcfg)
-                    else:
-                        y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm, None,
-                                                    qcfg)
-                    x = x + y
-                    new_pools.append(_mask_state(st, pool, do_prefill))
+                    with jax.named_scope(kind):
+                        h = apply_norm(p["ln1"], x, cfg.norm_kind)
+                        if kind == "mamba":
+                            y, st = ssm_lib.apply_mamba(p["mamba"], h,
+                                                        cfg.ssm, qcfg)
+                        elif kind == "mlstm":
+                            y, st = xlstm_lib.mlstm_seq(
+                                p, h, cfg.num_heads, cfg.xlstm, None, qcfg)
+                        else:
+                            y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm,
+                                                        None, qcfg)
+                        x = x + y
+                        new_pools.append(_mask_state(st, pool, do_prefill))
                 x, _ = model_lib._apply_ff(p, cfg, x, is_moe, qcfg)
             return x, tuple(new_pools)
 
         x, new_pools = jax.lax.scan(body, x, (params["blocks"],
                                               tuple(pools)))
-        x = apply_norm(params["final_norm"], x, cfg.norm_kind)
-        # logits at each request's last prompt position
-        idx = jnp.clip(lens - 1, 0, Lp - 1)
-        x_last = jnp.take_along_axis(
-            x, idx[:, None, None].astype(jnp.int32), axis=1)
-        logits = model_lib.unembed(params, cfg, x_last, qcfg)
-        first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        new_seq = jnp.where(do_prefill, lens, seq_lens)
+        with jax.named_scope("unembed"):
+            x = apply_norm(params["final_norm"], x, cfg.norm_kind)
+            # logits at each request's last prompt position
+            idx = jnp.clip(lens - 1, 0, Lp - 1)
+            x_last = jnp.take_along_axis(
+                x, idx[:, None, None].astype(jnp.int32), axis=1)
+            logits = model_lib.unembed(params, cfg, x_last, qcfg)
+            first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            new_seq = jnp.where(do_prefill, lens, seq_lens)
         return first, list(new_pools), new_seq
 
     return prefill_fn, decode_fn
