@@ -29,7 +29,10 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens):
     s = jnp.einsum("bhgd,bhkd->bhgk", qg, k).astype(jnp.float32)
     s = s / jnp.sqrt(jnp.float32(D))
     mask = jnp.arange(S)[None] < seq_lens[:, None]          # (B, S)
-    s = jnp.where(mask[:, None, None], s, -1e30)            # finite: matches
-    p = jax.nn.softmax(s, axis=-1)                          # kernel at len=0
+    s = jnp.where(mask[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(v.dtype), v)
+    # a row of length 0 (a free slot) attends to nothing: zeros, as the
+    # kernel writes, not the mean of whatever its table names
+    o = jnp.where(seq_lens[:, None, None, None] > 0, o, 0)
     return o.reshape(B, Hq, D).astype(q.dtype)

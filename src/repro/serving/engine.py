@@ -53,6 +53,18 @@ from repro.serving.resilience import (FailureSpec, FailureTimeline,
 from repro.serving.tracing import span
 
 
+def _kv_pages_read(context_lens: np.ndarray, steps: int, page: int) -> int:
+    """Pages of K/V the paged kernel reads over `steps` decode steps of
+    rows holding `context_lens` tokens: step s attends over
+    context + 1 + s tokens, `ceil(. / page)` pages of them. Closed form
+    of that sum: F(n) = sum of ceil(x / page) for x in 1..n."""
+    def pages_to(n):
+        q, r = np.divmod(n.astype(np.int64), page)
+        return page * q * (q + 1) // 2 + r * (q + 1)
+    return int(np.sum(pages_to(context_lens + steps)
+                      - pages_to(context_lens)))
+
+
 @dataclasses.dataclass
 class EngineConfig:
     max_batch: int = 8
@@ -401,6 +413,16 @@ class Engine:
             tokens[r.slot, :r.prompt_len] = row[:r.prompt_len]
         return tokens
 
+    def _count_kv_pages(self, context_lens: np.ndarray, steps: int):
+        """Per decode step, the pages of K/V the running rows' attention
+        reads, and the block-table entries a walk of every page holds."""
+        self.metrics.inc("repro:decode_kv_pages_live_total",
+                         _kv_pages_read(context_lens, steps,
+                                        self.cfg.page_size))
+        self.metrics.inc("repro:decode_kv_pages_table_total",
+                         steps * len(context_lens)
+                         * self.cfg.max_pages_per_seq)
+
     # ---- fast path ----------------------------------------------------
     def _run_fast(self, requests: Sequence[Request], *,
                   horizon: Optional[float] = None,
@@ -542,6 +564,7 @@ class Engine:
                     act = self.active
                     if needs_tok:
                         self.slot_tokens[act] = nxt[act]
+                    self._count_kv_pages(self.context_lens[act], steps)
                     self.tokens_out_arr[act] += steps
                     self.context_lens[act] += steps
                     self.metrics.inc("repro:generation_tokens_total",
@@ -662,6 +685,8 @@ class Engine:
                 with span("engine.update"):
                     self._advance(dt)
                     self.n_decode_steps += 1
+                    self._count_kv_pages(
+                        self.context_lens[[r.slot for r in running]], 1)
                     for r in running:
                         r.tokens_out += 1
                         r.prev_token_time = self.t

@@ -13,7 +13,7 @@ import pytest
 from repro.configs import get_config
 from repro.serving import (ArrivalSpec, Engine, EngineConfig, SimExecutor,
                            synth_requests)
-from repro.serving.request import RequestState
+from repro.serving.request import Request, RequestState
 from repro.simulate import StepTimeModel, V5E, V5P
 
 RTOL = 1e-9
@@ -58,7 +58,9 @@ def _assert_equivalent(ref, fast):
     for key in ("repro:generation_tokens_total",
                 "repro:prompt_tokens_total",
                 "repro:request_success_total",
-                "repro:request_preempted_total"):
+                "repro:request_preempted_total",
+                "repro:decode_kv_pages_live_total",
+                "repro:decode_kv_pages_table_total"):
         assert eref.metrics.get(key) == efast.metrics.get(key), key
 
 
@@ -294,3 +296,21 @@ def test_metrics_reset_clears_gauges_and_keeps_bound_hists():
     eng.run(reqs2)
     assert m.hists["repro:e2e_request_latency_seconds"].n == 15
     assert sum(m.hists["repro:e2e_request_latency_seconds"].counts) == 15
+
+
+@pytest.mark.parametrize("fast_forward", [True, False])
+def test_decode_kv_page_counters_match_a_hand_count(fast_forward):
+    """Per decode step, the pages of K/V the running rows attend over
+    (context + 1 tokens, 16 a page) and the table entries a walk of every
+    page holds (rows x max_pages_per_seq), on both scheduler paths."""
+    eng = _engine(fast_forward, max_batch=4, max_pages_per_seq=8)
+    # (prompt, max_new): one prefill, then 3, 2 and 1 decode steps
+    reqs = [Request(i, 0.0, n, m)
+            for i, (n, m) in enumerate([(15, 4), (16, 3), (40, 2)])]
+    eng.run(reqs)
+    assert eng.n_decode_steps == 3
+    # attends over 16, 17, 18 | 17, 18 | 41 tokens
+    live = (1 + 2 + 2) + (2 + 2) + 3
+    table = (3 + 2 + 1) * 8
+    assert eng.metrics.get("repro:decode_kv_pages_live_total") == live
+    assert eng.metrics.get("repro:decode_kv_pages_table_total") == table

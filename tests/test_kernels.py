@@ -38,25 +38,88 @@ def test_flash_attention(B, Sq, Sk, Hq, Hkv, D, causal, dtype):
                                np.asarray(ref, np.float32), atol=tol)
 
 
+# Lengths of the edge cases of `test_paged_attention`, keyed by the case;
+# the other cases draw theirs. At page 16 a grid step covers up to 64
+# pages, 1024 tokens, and 32 at phi3-medium's widths
+# (`kernel.pages_per_block`).
+EDGE_LENS = {
+    (4, 16, 8, 64, 16, 20, 96): (0, 320, 0, 320),    # empty next to full
+    (6, 16, 8, 64, 16, 80, 128): (1, 16, 17, 1023, 1024, 1025),  # page,
+    #                                    block edges; 80 pages = 1.25 blocks
+    (3, 16, 8, 64, 16, 5, 16): (80, 1, 33),          # table under one block
+    (4, 16, 8, 64, 16, 20, 12): (320, 200, 77, 319),  # rows share pages
+    (2, 8, 8, 64, 16, 20, 48): (300, 40),            # G = 1
+    (2, 16, 4, 128, 16, 20, 48): (300, 40),          # G = 4, D = 128
+    (2, 16, 2, 128, 16, 80, 48): (1100, 513),        # G = 8, two blocks
+    (3, 40, 10, 128, 16, 40, 64): (600, 0, 512),     # phi3-medium widths
+}
+
+
 @pytest.mark.parametrize("B,Hq,Hkv,D,page,maxp,P", [
     (2, 4, 2, 64, 8, 4, 16),
     (3, 8, 1, 128, 16, 3, 64),
     (1, 4, 4, 64, 8, 6, 12),
     (4, 8, 2, 64, 8, 5, 64),
     (2, 16, 8, 64, 16, 4, 32),           # granite-moe-1b-a400m widths
+    *EDGE_LENS,
 ])
 def test_paged_attention(B, Hq, Hkv, D, page, maxp, P):
     """Head-major pools (Hkv, P, page, D): the kernel in interpret mode
-    against the gather-then-dense reference."""
-    q = _randn((B, Hq, D))
-    kp, vp = _randn((Hkv, P, page, D)), _randn((Hkv, P, page, D))
-    bt = jnp.asarray(RNG.choice(P, size=(B, maxp),
+    against the gather-then-dense reference. A pool smaller than the
+    tables makes rows share pages."""
+    edge = EDGE_LENS.get((B, Hq, Hkv, D, page, maxp, P))
+    rng = RNG if edge is None else np.random.default_rng(B * maxp + P)
+    q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.bfloat16)
+    kp, vp = (jnp.asarray(rng.normal(size=(Hkv, P, page, D)), jnp.bfloat16)
+              for _ in range(2))
+    bt = jnp.asarray(rng.choice(P, size=(B, maxp),
                                 replace=B * maxp > P), jnp.int32)
-    sl = jnp.asarray(RNG.integers(1, page * maxp + 1, size=(B,)), jnp.int32)
+    sl = jnp.asarray(rng.integers(1, page * maxp + 1, size=(B,))
+                     if edge is None else edge, jnp.int32)
     out = paged_attention(q, kp, vp, bt, sl, use_kernel=True, interpret=True)
     ref = paged_attention_ref(q, kp, vp, bt, sl)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=0.06)
+
+
+def test_step_pages_hold_the_last_live_page():
+    """The page each pool operand holds at each grid step: the table's
+    where the page is live, else what the operand held the step before,
+    so the pipeline copies nothing at or past a row's length."""
+    from repro.kernels.paged_attention.kernel import step_pages
+    page, ppb = 4, 2
+    bt = np.arange(100, 115).reshape(3, 5)
+    lens = (9, 0, 4)
+    held, want = [bt[0, 0]] * ppb, []
+    for b in range(3):
+        for i in range(3):                  # ceil(5 pages / 2 a block)
+            for j in range(ppb):
+                p = i * ppb + j
+                if p < 5 and p * page < lens[b]:
+                    held[j] = bt[b, p]
+                want.append(held[j])
+    got = step_pages(jnp.asarray(bt, jnp.int32), jnp.asarray(lens, jnp.int32),
+                     page, ppb)
+    np.testing.assert_array_equal(np.asarray(got).ravel(), want)
+
+
+def test_paged_attention_is_one_named_call():
+    """All of the kernel's work is one `pallas_call` named
+    `paged_attention` (the name the roofline reads in a trace): nothing
+    but reshapes follows it."""
+    from repro.kernels.paged_attention.kernel import paged_attention_kernel
+    B, Hq, Hkv, D, page, maxp = 4, 16, 8, 64, 16, 20
+    args = (jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16),
+            *[jax.ShapeDtypeStruct((Hkv, 64, page, D), jnp.bfloat16)] * 2,
+            jax.ShapeDtypeStruct((B, maxp), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+    (jitted,) = jax.make_jaxpr(paged_attention_kernel)(*args).jaxpr.eqns
+    inner = jitted.params["jaxpr"].jaxpr.eqns
+    eqns = [e.primitive.name for e in inner]
+    assert eqns.count("pallas_call") == 1
+    call = inner[eqns.index("pallas_call")]
+    assert call.params["name"] == "paged_attention"
+    assert set(eqns[eqns.index("pallas_call") + 1:]) <= {"reshape"}
 
 
 @pytest.mark.parametrize("M,K,N", [(256, 256, 256), (512, 512, 256),
