@@ -7,7 +7,8 @@ they hold in memory.
 
 The step functions are the program's own (`serving/runner.py`), built
 with the Pallas paged kernel as a TPU serves them; the arguments are
-shapes only.
+shapes only, the served weights' as the configuration's architecture
+(`chipbench/arch.py`) makes them.
 """
 from __future__ import annotations
 
@@ -30,8 +31,8 @@ def main(config_path: str, prefill_len: int) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    from chipbench import arch
     from chipbench.run import model_config
-    from repro.models import init_params
     from repro.serving.runner import init_pools, make_step_fns
 
     cj = json.loads(Path(config_path).read_text())
@@ -48,7 +49,7 @@ def main(config_path: str, prefill_len: int) -> int:
             tree)
 
     params = on_chip(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+        lambda: arch.resolve(cj).program_params(cj, 0)))
     pools = on_chip(jax.eval_shape(
         lambda: init_pools(cfg, B * P + 1, page, B)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)  # noqa: E731

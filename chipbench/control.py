@@ -36,7 +36,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from chipbench import run as bench, weights  # noqa: E402
+from chipbench import arch, run as bench  # noqa: E402
 
 
 def build_int8(cj: dict, seed: int):
@@ -46,7 +46,7 @@ def build_int8(cj: dict, seed: int):
     bfloat16 weights and one leaf's temporaries at most."""
     import jax
     from repro.quant import INT8, quantize_tree
-    params = weights.program_params(cj, bench.weight_seed(seed))
+    params = arch.resolve(cj).program_params(cj, bench.weight_seed(seed))
 
     def visit(tree):
         if isinstance(tree, list):

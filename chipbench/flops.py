@@ -26,7 +26,8 @@ class Shape:
     def of(cls, cj: dict) -> "Shape":
         d, h = cj["hidden_size"], cj["num_attention_heads"]
         return cls(layers=cj["num_hidden_layers"], d=d, hq=h,
-                   hkv=cj["num_key_value_heads"], hd=d // h,
+                   hkv=cj["num_key_value_heads"],
+                   hd=cj.get("head_dim") or d // h,
                    ff=cj["intermediate_size"], vocab=cj["vocab_size"],
                    experts=cj.get("num_local_experts", 0),
                    top_k=cj.get("num_experts_per_tok", 0))
@@ -62,3 +63,11 @@ class Shape:
         K and V of `keys` tokens, the query and the output."""
         kv = 2 * keys * self.hkv * self.hd * self.kv_bytes
         return kv + 2 * self.hq * self.hd * 2
+
+    def decode_attn_bytes(self, keys: int) -> int:
+        """`paged_attn_bytes` over every layer."""
+        return self.layers * self.paged_attn_bytes(keys)
+
+    def decode_attn_flops(self, keys: int) -> int:
+        """`attn_flops` of one decoded token over every layer."""
+        return self.layers * self.attn_flops(keys)

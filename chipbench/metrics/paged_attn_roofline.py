@@ -12,8 +12,8 @@ def bound(run):
     nbytes = flops = 0
     for s in run.traced_steps("decode"):
         for k in s.ctx:
-            nbytes += shape.layers * shape.paged_attn_bytes(k)
-            flops += shape.layers * shape.attn_flops(k)
+            nbytes += shape.decode_attn_bytes(k)
+            flops += shape.decode_attn_flops(k)
     t_mem = nbytes / run.peaks["hbm_bytes_per_s"]
     t_cmp = flops / run.peaks["bf16_flops_per_s"]
     return max(t_mem, t_cmp), ("memory" if t_mem >= t_cmp else "compute")

@@ -11,6 +11,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence
 
+from chipbench import arch
 from chipbench.flops import Shape
 from chipbench.trace_reduce import Reduced
 from chipbench.wallclock import Step
@@ -44,7 +45,7 @@ class Run:
 
     @property
     def shape(self) -> Shape:
-        return Shape.of(self.config)
+        return arch.resolve(self.config).shape(self.config)
 
     @property
     def open_loop(self) -> bool:

@@ -39,8 +39,8 @@ BATCH = 4                       # sequences per reference call
 def dims(cj: dict) -> tuple:
     """The model's dimensions as sorted (name, value) pairs: hashable, so
     they can be a static jit argument."""
-    d, h = cj["hidden_size"], cj["num_attention_heads"]
-    m = dict(d=d, hq=h, hkv=cj["num_key_value_heads"], hd=d // h,
+    m = dict(d=cj["hidden_size"], hq=cj["num_attention_heads"],
+             hkv=cj["num_key_value_heads"], hd=weights.head_dim(cj),
              layers=cj["num_hidden_layers"],
              experts=cj.get("num_local_experts", 0),
              top_k=cj.get("num_experts_per_tok", 0),
@@ -72,10 +72,9 @@ def _swiglu(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
-@functools.partial(jax.jit, static_argnames=("dm",))
-def _block(x, p, dm):
-    """One decoder layer on (N, L, d) float32."""
-    m = dict(dm)
+def attend(x, p, m: dict):
+    """x (N, L, d) float32 plus its pre-norm attention sublayer, times the
+    residual multiplier."""
     N, L, d = x.shape
     hq, hkv, hd = m["hq"], m["hkv"], m["hd"]
     h = _rms(x, m["eps"])
@@ -88,7 +87,15 @@ def _block(x, p, dm):
     causal = jnp.tril(jnp.ones((L, L), bool))
     s = jnp.where(causal, s, -jnp.inf)
     o = jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, -1), v)
-    x = x + m["m_residual"] * (o.reshape(N, L, hq * hd) @ p["wo"])
+    return x + m["m_residual"] * (o.reshape(N, L, hq * hd) @ p["wo"])
+
+
+@functools.partial(jax.jit, static_argnames=("dm",))
+def _block(x, p, dm):
+    """One decoder layer on (N, L, d) float32."""
+    m = dict(dm)
+    x = attend(x, p, m)
+    N, L, d = x.shape
     h = _rms(x, m["eps"]).reshape(N * L, d)
     if m["experts"]:
         probs = jax.nn.softmax(h @ p["router"], -1)
@@ -116,11 +123,13 @@ def _gaps(x, rows, cols, served, emb, final_scale, dm):
 
 
 def served_gaps(cj: dict, seed: int, seqs: Sequence[Sequence[int]],
-                prompt_lens: Sequence[int], length: int) -> np.ndarray:
+                prompt_lens: Sequence[int], length: int,
+                block=_block) -> np.ndarray:
     """The logit gap of every served token of `seqs` (prompt + served
     tokens, each at most `length` long), computed `BATCH` sequences at a
     time at fixed shapes, so that every run reuses the same compiled
-    programs."""
+    programs. `block(x, layer's published weights, dims(cj))` is one
+    layer; an architecture module hands its own."""
     dm = dims(cj)
     emb = _published(cj, weights.embed_weights(cj, seed))
     final_scale = weights.published(cj, "final_norm", jnp.float32(1))
@@ -148,7 +157,7 @@ def served_gaps(cj: dict, seed: int, seqs: Sequence[Sequence[int]],
             picks.append(tuple(map(jnp.asarray, (rows, cols, served, valid))))
         for layer in range(dict(dm)["layers"]):
             p = _published(cj, weights.layer_weights(cj, seed, layer))
-            xs = [_block(x, p, dm) for x in xs]
+            xs = [block(x, p, dm) for x in xs]
         out = [np.asarray(_gaps(x, r, c, s, emb, final_scale, dm))
                [np.asarray(v)] for x, (r, c, s, v) in zip(xs, picks)]
     return np.concatenate(out)

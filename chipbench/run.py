@@ -21,8 +21,9 @@ the per-layer metrics are read from it; otherwise the end-to-end ones.
 
 After the window: the device's peak memory is read, the program's state
 is freed, and a seeded sample of the requests the window finished (the
-longest among them) is checked against the float32 reference
-(`chipbench/reference.py`): for each served token, the gap by which its
+longest among them) is checked against the float32 reference of the
+configuration's architecture (`chipbench/arch.py`; by default
+`chipbench/reference.py`): for each served token, the gap by which its
 reference logit lies below the reference's best there. `correct` holds
 where the gap statistics the cell's limits file names are within their
 limits, and the exact checks pass. The last
@@ -56,7 +57,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from chipbench import reference, trace_reduce, weights  # noqa: E402
+from chipbench import arch, trace_reduce  # noqa: E402
 from chipbench.record import Run  # noqa: E402
 from chipbench.traffic import load_mix, make_traffic  # noqa: E402
 from chipbench.wallclock import WallClock  # noqa: E402
@@ -94,6 +95,53 @@ class CompileCounter:
     def __exit__(self, *exc):
         import jax
         jax.monitoring.unregister_event_duration_listener(self)
+
+
+class GcPauses:
+    """Records the collections of Python's garbage collector while active,
+    as (start, seconds, generation) on the perf_counter clock."""
+
+    def __init__(self):
+        self.pauses = []
+        self._t = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self._t is not None:
+            self.pauses.append((self._t, time.perf_counter() - self._t,
+                                info["generation"]))
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+    def describe(self) -> str:
+        if not self.pauses:
+            return "none"
+        t, d, g = max(self.pauses, key=lambda p: p[1])
+        total = 1e3 * sum(p[1] for p in self.pauses)
+        return (f"{len(self.pauses)}, {total:.3f} ms in all, the longest "
+                f"{1e3 * d:.3f} ms (generation {g})")
+
+
+def describe_steps(run) -> str:
+    """The window's steps by kind (count, median and longest ms) and the
+    longest host gap between two steps: where a slow window lost time."""
+    parts = []
+    for kind in ("prefill", "decode"):
+        ms = [1e3 * (s.end - s.start) for s in run.window_steps(kind)]
+        if ms:
+            parts.append(f"{kind} {len(ms)}, median {np.median(ms):.3f} "
+                         f"ms, longest {max(ms):.3f} ms")
+    inside = [s for s in run.steps if run.w0 <= s.end <= run.w1]
+    gaps = [1e3 * (b.start - a.end) for a, b in zip(inside, inside[1:])]
+    if gaps:
+        parts.append(f"longest gap between steps {max(gaps):.3f} ms")
+    return "; ".join(parts)
 
 
 # -- the cell's files ---------------------------------------------------
@@ -140,25 +188,48 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
+# ModelConfig and MoEConfig fields the published keys give
+PUBLISHED = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
+             "num_heads": "num_attention_heads",
+             "num_kv_heads": "num_key_value_heads",
+             "d_ff": "intermediate_size", "vocab_size": "vocab_size",
+             "head_dim": "head_dim", "rope_theta": "rope_theta",
+             "tie_embeddings": "tie_word_embeddings"}
+PUBLISHED_MOE = {"num_experts": "num_local_experts",
+                 "top_k": "num_experts_per_tok",
+                 "expert_ff": "intermediate_size"}
+
+
 def model_config(cj: dict):
-    """The program's `ModelConfig` for a configuration file."""
-    from repro.configs.base import ModelConfig, MoEConfig
-    prog = cj["program"]
-    moe = None
-    if cj.get("num_local_experts"):
-        moe = MoEConfig(num_experts=cj["num_local_experts"],
-                        top_k=cj["num_experts_per_tok"],
-                        expert_ff=cj["intermediate_size"],
-                        capacity_factor=prog["moe_capacity_factor"],
-                        group_size=prog["moe_group_size"])
-    return ModelConfig(
-        name=cj["name"], family=prog["family"],
-        num_layers=cj["num_hidden_layers"], d_model=cj["hidden_size"],
-        num_heads=cj["num_attention_heads"],
-        num_kv_heads=cj["num_key_value_heads"],
-        d_ff=cj["intermediate_size"], vocab_size=cj["vocab_size"],
-        mlp_kind=prog["mlp_kind"], rope_theta=float(cj["rope_theta"]),
-        tie_embeddings=bool(cj["tie_word_embeddings"]), moe=moe)
+    """The program's `ModelConfig` for a configuration file: the sizes its
+    published keys give, and each key of its `program` block, which names
+    any other field of `ModelConfig`, or of `MoEConfig` bare or as
+    `moe_<field>`; a group (`ssm`, `xlstm`) is an object of its fields.
+    A key that names no such field, or one the published keys give,
+    raises."""
+    from repro.configs import base
+    groups = {"ssm": base.SSMConfig, "xlstm": base.XLSTMConfig}
+    top = {f.name for f in dataclasses.fields(base.ModelConfig)} - {
+        "name", "moe"}
+    moe_fields = {f.name for f in dataclasses.fields(base.MoEConfig)}
+    kw = {f: cj[k] for f, k in PUBLISHED.items() if k in cj}
+    kw["rope_theta"] = float(kw["rope_theta"])
+    kw["tie_embeddings"] = bool(kw["tie_embeddings"])
+    moe = ({f: cj[k] for f, k in PUBLISHED_MOE.items()}
+           if cj.get("num_local_experts") else None)
+    for key, value in cj.get("program", {}).items():
+        bare = key[len("moe_"):] if key.startswith("moe_") else key
+        if key in top and key not in kw:
+            kw[key] = (groups[key](**value) if key in groups else value)
+        elif bare in moe_fields and moe is not None and bare not in moe:
+            moe[bare] = value
+        else:
+            raise ValueError(
+                f"{cj['name']}: program key {key!r} names no field of "
+                f"ModelConfig or MoEConfig that the published keys leave "
+                f"open")
+    return base.ModelConfig(
+        name=cj["name"], moe=moe and base.MoEConfig(**moe), **kw)
 
 
 def weight_seed(seed: int) -> int:
@@ -172,14 +243,14 @@ def build(cj: dict, seed: int, params=None, qcfg=None):
     """The engine `repro.launch.serve.build_engine` makes for the real
     tier (a `RealExecutor` with a pool of every slot's pages and the trash
     page, `EngineConfig` defaults otherwise), serving the benchmark's
-    weights (`chipbench/weights.py`) in place of the program's."""
+    weights (`chipbench/arch.py`) in place of the program's."""
     from repro.launch.serve import PAGE_SIZE
     from repro.serving import Engine, EngineConfig, RealExecutor
     e = cj["engine"]
     mb, mp = e["max_batch"], e["max_pages_per_seq"]
     npg = mb * mp + 1
     if params is None:
-        params = weights.program_params(cj, weight_seed(seed))
+        params = arch.resolve(cj).program_params(cj, weight_seed(seed))
     ex = RealExecutor(model_config(cj), params, num_pages=npg,
                       page_size=PAGE_SIZE, max_batch=mb, qcfg=qcfg)
     return Engine(EngineConfig(max_batch=mb, page_size=PAGE_SIZE,
@@ -207,6 +278,7 @@ class Served:
     lateness: list
     compiles: int
     meter_usd_per_mtok: float
+    gc_pauses: str                  # the window's garbage collections
 
 
 def serve(cell: dict, config: dict, mix: dict, peaks: dict, *, seed: int,
@@ -248,7 +320,7 @@ def serve(cell: dict, config: dict, mix: dict, peaks: dict, *, seed: int,
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     meter.tick()
-    with CompileCounter() as compiles:
+    with CompileCounter() as compiles, GcPauses() as gc_pauses:
         if trace:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0     # the benchmark's spans suffice
@@ -286,7 +358,7 @@ def serve(cell: dict, config: dict, mix: dict, peaks: dict, *, seed: int,
     summary = meter.summary()
     free(eng, clock)
     return Served(run, reqs, clock.lateness, compiles.n,
-                  summary["time_weighted_avg"])
+                  summary["time_weighted_avg"], gc_pauses.describe())
 
 
 def free(eng, clock):
@@ -345,7 +417,7 @@ def check(served: Served, config: dict, limits: dict, seed: int,
     served_ok = all(len(run.tokens[r.rid]) == r.max_new_tokens
                     for r in picked)
     t0 = time.perf_counter()
-    gaps = (reference.served_gaps(
+    gaps = (arch.resolve(config).served_gaps(
         config, weight_seed(seed),
         [list(r.prompt) + run.tokens[r.rid] for r in picked],
         [r.prompt_len for r in picked], length=run.mix["max_total"])
@@ -422,6 +494,8 @@ def measure(cell_name: str, seed: int, seconds: float, trace: bool, *,
         f"{1e3 * float(np.median(late)) if late else 0.0:.3f} ms, max "
         f"{1e3 * max(late) if late else 0.0:.3f} ms")
     log(f"compilations inside the window: {served.compiles}")
+    log(f"garbage collections inside the window: {served.gc_pauses}")
+    log(f"window steps: {describe_steps(run)}")
     if not trace:
         log(f"CostMeter over the window: {served.meter_usd_per_mtok} $/Mtok "
             f"(engine clock, to the end of the last step); benchmark "

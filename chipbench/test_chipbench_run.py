@@ -79,8 +79,22 @@ def test_result_line(cell, test_bench, kernel_path):
     assert all(m["value"] > 0 and math.isfinite(m["value"])
                for m in out["metrics"].values())
     assert any(s == "compilations inside the window: 0" for s in lines)
+    assert any(s.startswith("garbage collections inside the window: ")
+               for s in lines)
+    assert any(s.startswith("window steps: decode ") or
+               s.startswith("window steps: prefill ") for s in lines)
     assert out["checks"]["mean_logit_gap"]["limit"] == 1.0
     json.dumps(out)
+
+
+def test_gc_pauses_records_a_full_collection():
+    import gc
+    with run.GcPauses() as pauses:
+        gc.collect()
+    assert [g for _, _, g in pauses.pauses] == [2]
+    assert pauses.describe().startswith("1, ")
+    assert pauses not in gc.callbacks
+    assert run.GcPauses().describe() == "none"
 
 
 def test_open_loop_serves_every_request_due(test_bench, kernel_path):

@@ -5,9 +5,13 @@ They are a checkpoint in the program's form. The program has no scalar
 multipliers; a configuration that states them (granite's embedding,
 attention, residual and logits multipliers) is served with each folded
 into the weights it scales, as converting such a checkpoint for a
-runtime without them does (`folds`). `published` gives a matrix back in
-the published form, in float32, for the reference, which applies every
-multiplier where the published model does.
+runtime without them does (`folds`). Nor does it take a norm epsilon:
+every RMSNorm it computes has 1e-6. A configuration that states another
+(Phi-3's 1e-5) is served with its residual stream rescaled, which moves
+the epsilon the norms see (`residual_scale`). `published` gives a matrix
+back in the published form, in float32, for the reference, which
+applies every multiplier, and the stated epsilon, where the published
+model does.
 
 The program's own initialiser (normal, std 0.02, norm scales 1) makes a
 degenerate model: every layer adds little to a token's own embedding,
@@ -36,11 +40,17 @@ import jax.numpy as jnp
 
 LEVELS = 4096
 EMBED = 64                      # the fold-in index of the (un)embedding
+PROGRAM_NORM_EPS = 1e-6         # every RMSNorm of the program
+
+
+def head_dim(cj: dict) -> int:
+    """`head_dim` where the configuration states it, else d / heads."""
+    return cj.get("head_dim") or cj["hidden_size"] // cj["num_attention_heads"]
 
 
 def _dims(cj: dict):
-    d, h = cj["hidden_size"], cj["num_attention_heads"]
-    return (d, h, cj["num_key_value_heads"], d // h, cj["intermediate_size"],
+    return (cj["hidden_size"], cj["num_attention_heads"],
+            cj["num_key_value_heads"], head_dim(cj), cj["intermediate_size"],
             cj["vocab_size"], cj.get("num_local_experts", 0))
 
 
@@ -71,11 +81,23 @@ def _draw(key, shape, std):
 def multipliers(cj: dict) -> Dict[str, float]:
     """The configuration's scalar multipliers; a configuration that states
     none has 1, and the attention scale 1/sqrt(head_dim)."""
-    hd = cj["hidden_size"] // cj["num_attention_heads"]
+    hd = head_dim(cj)
     return {"embedding": float(cj.get("embedding_multiplier", 1.0)),
             "attention": float(cj.get("attention_multiplier", hd ** -0.5)),
             "residual": float(cj.get("residual_multiplier", 1.0)),
             "logits": float(cj.get("logits_scaling", 1.0))}
+
+
+def residual_scale(cj: dict) -> float:
+    """Factor c by which the program's residual stream is the published
+    one: sqrt(program epsilon / published epsilon). RMSNorm of c x at
+    epsilon c^2 e is RMSNorm of x at e, and nothing else in a block sees
+    the stream's scale, so with the embedding and each layer's last
+    matrices scaled by c the program computes, at its own epsilon, the
+    published model at the stated one. A `program` block that names
+    `rms_norm_eps` hands the program the epsilon itself."""
+    program = cj.get("program", {}).get("rms_norm_eps", PROGRAM_NORM_EPS)
+    return math.sqrt(float(program) / float(cj["rms_norm_eps"]))
 
 
 def folds(cj: dict) -> Dict[str, float]:
@@ -85,15 +107,19 @@ def folds(cj: dict) -> Dict[str, float]:
     1/sqrt(head_dim) (queries carry the rest of the attention
     multiplier), adds each layer's output unscaled (its last matrix
     carries the residual multiplier) and looks up the embedding
-    unscaled; the final norm's scale divides out the embedding multiplier
-    the tied unembed then carries, and the logits scaling. Norm scales
-    are 1 in the program's form."""
+    unscaled; the embedding and each layer's last matrix carry the
+    residual stream's scale (`residual_scale`); the final norm's scale
+    divides out what the tied unembed then carries, the embedding
+    multiplier and the stream's scale, and the logits scaling. Norm
+    scales are 1 in the program's form."""
     m = multipliers(cj)
-    hd = cj["hidden_size"] // cj["num_attention_heads"]
+    c = residual_scale(cj)
     tied = bool(cj["tie_word_embeddings"])
-    return {"wq": m["attention"] * hd ** 0.5, "wo": m["residual"],
-            "down": m["residual"], "embed": m["embedding"],
-            "final_norm": 1 / (m["logits"] * (m["embedding"] if tied else 1))}
+    return {"wq": m["attention"] * head_dim(cj) ** 0.5,
+            "wo": m["residual"] * c, "down": m["residual"] * c,
+            "embed": m["embedding"] * c,
+            "final_norm": 1 / (m["logits"]
+                               * (m["embedding"] * c if tied else 1))}
 
 
 def published(cj: dict, name: str, w) -> jnp.ndarray:
