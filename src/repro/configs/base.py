@@ -49,15 +49,34 @@ class MoEConfig:
     # every `interleave`-th layer is MoE (1 = all layers, 2 = alternating)
     interleave: int = 1
     shared_expert_ff: int = 0      # optional always-on shared expert
+    # expert share (expert parallelism): the router scores `router_experts`
+    # experts (0 -> num_experts); this layer holds `num_experts` of them,
+    # those from `expert_offset` on, and computes only their part
+    router_experts: int = 0
+    expert_offset: int = 0
+
+    @property
+    def routed(self) -> int:
+        """Experts the router scores (its published width)."""
+        return self.router_experts or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class SSMConfig:
-    """Mamba-style selective SSM block parameters."""
+    """Selective SSM block parameters: Mamba-1 (per-channel A, x_proj and
+    dt_proj) or Mamba-2 (`kind="mamba2"`: a scalar A per head, B and C
+    shared over `n_groups`, a gated RMSNorm before out_proj)."""
     d_state: int = 16
     d_conv: int = 4
     expand: int = 2                # d_inner = expand * d_model
-    dt_rank: int = 0               # 0 -> ceil(d_model/16)
+    dt_rank: int = 0               # 0 -> ceil(d_model/16) (Mamba-1)
+    kind: str = "mamba1"           # mamba1 | mamba2
+    n_heads: int = 0               # Mamba-2: d_inner = n_heads * head_dim
+    head_dim: int = 0
+    n_groups: int = 1              # Mamba-2: groups sharing B and C
+    chunk: int = 256               # Mamba-2 prefill chunk (any length
+    #                                gives the same result; memory only)
+    norm_eps: float = 1e-5         # Mamba-2 gated RMSNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,6 +189,14 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
     for i, kind in enumerate(pattern):
         if kind == "attn":
             total += attn
+        elif kind == "mamba" and cfg.ssm.kind == "mamba2":
+            s = cfg.ssm
+            di = s.expand * d
+            conv = di + 2 * s.n_groups * s.d_state
+            # in_proj (z, xBC, dt), conv and its bias, dt_bias, A, D,
+            # the gated norm, out_proj
+            total += d * (di + conv + s.n_heads) + conv * (s.d_conv + 1)
+            total += 3 * s.n_heads + di + di * d
         elif kind == "mamba":
             assert cfg.ssm is not None
             di = cfg.ssm.expand * d
@@ -194,7 +221,7 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         if kind == "attn" or cfg.family == "hybrid":
             if moe_mask[i] and cfg.moe is not None:
                 e = cfg.moe.top_k if active_only else cfg.moe.num_experts
-                total += e * mlp(cfg.moe.expert_ff) + d * cfg.moe.num_experts
+                total += e * mlp(cfg.moe.expert_ff) + d * cfg.moe.routed
                 total += mlp(cfg.moe.shared_expert_ff)
             else:
                 total += mlp(cfg.d_ff)

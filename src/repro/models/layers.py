@@ -77,6 +77,14 @@ def apply_mlp(p, x, kind: str, qcfg=None):
     return linear(h, p["down"], qcfg)
 
 
+def keep_rows(active, new, old):
+    """Per-slot state select: rows where `active` (B,) holds take `new`,
+    the others keep `old` (pytrees of arrays with a leading B axis)."""
+    def pick(n, o):
+        return jnp.where(active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+    return jax.tree.map(pick, new, old)
+
+
 def softcap(logits, cap: float):
     if not cap:
         return logits

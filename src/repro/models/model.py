@@ -424,9 +424,8 @@ def _block_cache_shape(cfg: ModelConfig, kind: str, B: int, S_max: int,
             out["xk"] = ((R, B, enc_len, cfg.num_kv_heads, hd), jnp.bfloat16)
             out["xv"] = ((R, B, enc_len, cfg.num_kv_heads, hd), jnp.bfloat16)
     elif kind == "mamba":
-        di = cfg.ssm.expand * d
-        out = {"conv": ((R, B, cfg.ssm.d_conv - 1, di), jnp.bfloat16),
-               "h": ((R, B, di, cfg.ssm.d_state), jnp.float32)}
+        out = {name: ((R, B) + shape, dtype) for name, (shape, dtype)
+               in ssm_lib.state_shapes(d, cfg.ssm).items()}
     elif kind == "mlstm":
         di = int(cfg.xlstm.mlstm_proj_factor * d)
         dh = di // cfg.num_heads

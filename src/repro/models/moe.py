@@ -6,6 +6,13 @@ TPU MoE). Dispatch/combine are one-hot einsums so that, with the expert axis
 sharded over `model` (EP), XLA emits all-to-all on the group<->expert
 resharding boundary — the paper's MoE cost behaviour (§3.3, §5.2) then shows
 up directly in the roofline's collective term.
+
+Expert share (`MoEConfig.router_experts`, `expert_offset`): the router
+scores all `routed` experts and the top-k softmax runs over them, but the
+layer holds only `num_experts` of them, from `expert_offset` on, and
+dispatches to and combines only those: its output is the held share's
+part of the routed sum (plus the shared expert, which every share
+computes). With neither set the layer holds every expert it routes to.
 """
 from __future__ import annotations
 
@@ -28,7 +35,7 @@ def init_moe(key, d: int, cfg: MoEConfig, mlp_kind: str, dtype=jnp.bfloat16):
     def w(k, shape):
         return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
     p = {
-        "router": w(ks[0], (d, E)).astype(jnp.float32),
+        "router": w(ks[0], (d, cfg.routed)).astype(jnp.float32),
         "experts_up": w(ks[1], (E, d, ff)),
         "experts_down": w(ks[2], (E, ff, d)),
     }
@@ -69,7 +76,7 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
     attributes device time to it.
     """
     B, S, d = x.shape
-    E, K = cfg.num_experts, cfg.top_k
+    E, K, Er = cfg.num_experts, cfg.top_k, cfg.routed
     T = B * S
     Sg = min(cfg.group_size, T)
     G = T // Sg
@@ -80,7 +87,7 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
     with jax.named_scope("route"):
         logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32),
                             p["router"])
-        probs = jax.nn.softmax(logits, axis=-1)                # (G,Sg,E)
+        probs = jax.nn.softmax(logits, axis=-1)                # (G,Sg,Er)
         gate_vals, gate_idx = jax.lax.top_k(probs, K)          # (G,Sg,K)
         gate_vals = gate_vals / jnp.maximum(
             jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
@@ -88,10 +95,13 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
         # Load-balancing aux loss (Switch-style):
         # E * mean(frac_tokens * mean_prob)
         frac = jnp.mean(
-            jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32),
+            jax.nn.one_hot(gate_idx[..., 0], Er, dtype=jnp.float32),
             axis=(0, 1))
-        aux = E * jnp.sum(frac * jnp.mean(probs, axis=(0, 1)))
+        aux = Er * jnp.sum(frac * jnp.mean(probs, axis=(0, 1)))
 
+        if cfg.expert_offset:
+            gate_idx = gate_idx - cfg.expert_offset
+        # experts not held here (index outside [0, E)) get no slot
         onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (G,Sg,K,E)
         # position of each (token, k) assignment within its expert's queue
         flat = onehot.reshape(G, Sg * K, E)

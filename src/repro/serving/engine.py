@@ -125,6 +125,7 @@ class Engine:
         # duration, so clock resets cannot skew it.
         self._ovl_state = 0                 # overload.NORMAL
         self._last_ttft = 0.0
+        self._set_state_gauge()
         # scheduler instrumentation (bench_engine_throughput)
         self.n_iterations = 0
         self.n_decode_steps = 0
@@ -151,6 +152,14 @@ class Engine:
         self._inflight_area = 0.0
         self._last_t = 0.0
         self.metrics.reset()
+        self._set_state_gauge()
+
+    def _set_state_gauge(self):
+        """`repro:recurrent_state_bytes`: the recurrent state the
+        executor's pools hold, where it holds any."""
+        nbytes = getattr(self.ex, "state_bytes", 0)
+        if nbytes:
+            self.metrics.set("repro:recurrent_state_bytes", nbytes)
 
     # ------------------------------------------------------------------
     def _complete(self, slot: int):

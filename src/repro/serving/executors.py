@@ -47,6 +47,10 @@ class RealExecutor:
         self.params = params
         self.page_size = page_size
         self.pools = init_pools(cfg, num_pages, page_size, max_batch)
+        # bytes of recurrent (SSM / xLSTM) state the pools hold, every slot
+        self.state_bytes = sum(
+            leaf.nbytes for pool in self.pools if "k" not in pool
+            for leaf in jax.tree.leaves(pool))
         self.seq_lens = jnp.zeros((max_batch,), jnp.int32)
         self.prefill_fn, self.decode_fn = make_step_fns(
             cfg, page_size, qcfg=qcfg, use_kernel=use_kernel)

@@ -10,7 +10,11 @@ Named scopes tile both steps (`embed`; `attention`, with its K/V write in
 attribute device time to them; the names are what benchmarks read.
 
 SSM / xLSTM / hybrid blocks keep per-slot O(1) states in the same state
-pytree (they have no KV pages — the reason those archs run long_500k).
+pytree (they have no KV pages — the reason those archs run long_500k);
+a prefill leaves each row's state at that row's own length. A Mamba
+block runs under `mamba` (Mamba-2: `mamba/conv`, `mamba/ssd` in prefill,
+`mamba/state_update` in decode, through the `ssm_decode` kernel on a TPU,
+and `mamba/out`); a shared expert under `moe/shared`.
 Encoder-decoder archs are not served by this engine (documented limitation;
 the dry-run covers their serve path).
 """
@@ -28,7 +32,7 @@ from repro.models import attention as attn_lib
 from repro.models import model as model_lib
 from repro.models import ssm as ssm_lib
 from repro.models import xlstm as xlstm_lib
-from repro.models.layers import apply_norm
+from repro.models.layers import apply_norm, keep_rows
 from repro.quant import linear
 
 
@@ -38,20 +42,19 @@ def init_pools(cfg: ModelConfig, num_pages: int, page_size: int,
     U = model_lib.unit_size(cfg)
     R = cfg.num_layers // U
     hd = cfg.resolved_head_dim
+    act = jnp.dtype(cfg.dtype)          # K/V and conv tails: activations'
     pools: List[Dict[str, Any]] = []
     for kind, _ in model_lib.unit_pattern(cfg):
         if kind == "attn":
             # head-major pages: the paged kernel's block layout
             shape = (R, cfg.num_kv_heads, num_pages, page_size, hd)
-            pools.append({"k": jnp.zeros(shape, jnp.bfloat16),
-                          "v": jnp.zeros(shape, jnp.bfloat16)})
+            pools.append({"k": jnp.zeros(shape, act),
+                          "v": jnp.zeros(shape, act)})
         elif kind == "mamba":
-            di = cfg.ssm.expand * cfg.d_model
             pools.append({
-                "conv": jnp.zeros((R, max_batch, cfg.ssm.d_conv - 1, di),
-                                  jnp.bfloat16),
-                "h": jnp.zeros((R, max_batch, di, cfg.ssm.d_state),
-                               jnp.float32)})
+                name: jnp.zeros((R, max_batch) + shape, dtype)
+                for name, (shape, dtype) in
+                ssm_lib.state_shapes(cfg.d_model, cfg.ssm, act).items()})
         elif kind == "mlstm":
             di = int(cfg.xlstm.mlstm_proj_factor * cfg.d_model)
             dh = di // cfg.num_heads
@@ -91,14 +94,6 @@ def _scatter_kv(pool_k, pool_v, k, v, block_tables, positions, active,
     pool_k = pool_k.at[:, pf, of].set(kf.astype(pool_k.dtype))
     pool_v = pool_v.at[:, pf, of].set(vf.astype(pool_v.dtype))
     return pool_k, pool_v
-
-
-def _mask_state(new, old, active):
-    """Per-slot state update mask (active: (B,) bool)."""
-    def pick(n, o):
-        a = active.reshape((-1,) + (1,) * (n.ndim - 1))
-        return jnp.where(a, n, o)
-    return jax.tree.map(pick, new, old)
 
 
 def make_decode_logits(cfg: ModelConfig, page_size: int, qcfg=None,
@@ -152,15 +147,18 @@ def make_decode_logits(cfg: ModelConfig, page_size: int, qcfg=None,
                         h = apply_norm(p["ln1"], x, cfg.norm_kind)
                         if kind == "mamba":
                             y, st = ssm_lib.mamba_decode_step(
-                                p["mamba"], h, pool, cfg.ssm, qcfg)
+                                p["mamba"], h, pool, cfg.ssm, qcfg,
+                                active=active, use_kernel=use_kernel)
                         elif kind == "mlstm":
                             y, st = xlstm_lib.mlstm_seq(
                                 p, h, cfg.num_heads, cfg.xlstm, pool, qcfg)
+                            st = keep_rows(active, st, pool)
                         else:
                             y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm,
                                                         pool, qcfg)
+                            st = keep_rows(active, st, pool)
                         x = x + y
-                        new_pools.append(_mask_state(st, pool, active))
+                        new_pools.append(st)
                 x, _ = model_lib._apply_ff(p, cfg, x, is_moe, qcfg)
             return x, tuple(new_pools)
 
@@ -239,7 +237,8 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
                         h = apply_norm(p["ln1"], x, cfg.norm_kind)
                         if kind == "mamba":
                             y, st = ssm_lib.apply_mamba(p["mamba"], h,
-                                                        cfg.ssm, qcfg)
+                                                        cfg.ssm, qcfg,
+                                                        lens=lens)
                         elif kind == "mlstm":
                             y, st = xlstm_lib.mlstm_seq(
                                 p, h, cfg.num_heads, cfg.xlstm, None, qcfg)
@@ -247,7 +246,7 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
                             y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm,
                                                         None, qcfg)
                         x = x + y
-                        new_pools.append(_mask_state(st, pool, do_prefill))
+                        new_pools.append(keep_rows(do_prefill, st, pool))
                 x, _ = model_lib._apply_ff(p, cfg, x, is_moe, qcfg)
             return x, tuple(new_pools)
 

@@ -114,3 +114,56 @@ def test_granite_decode_step_compiles_with_paged_kernel(one_chip):
     _, decode_fn = make_step_fns(cfg, page, use_kernel=True)
     compiled = decode_fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B", [1, 30])
+def test_ssm_decode_kernel_compiles(one_chip, B):
+    """The Mamba-2 state update at granite-4.0-h-small's widths (128
+    heads of 64, state 128, one group), one Mosaic call under its name."""
+    from repro.kernels.ssm_decode.kernel import ssm_state_update_kernel
+    f32 = jnp.float32
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((B, 64, 128, 128), f32),
+        jax.ShapeDtypeStruct((B, 128, 64), f32),
+        jax.ShapeDtypeStruct((B, 128), f32),
+        jax.ShapeDtypeStruct((128,), f32),
+        jax.ShapeDtypeStruct((B, 1, 128), f32),
+        jax.ShapeDtypeStruct((B, 1, 128), f32),
+        jax.ShapeDtypeStruct((128,), f32)))
+    compiled = ssm_state_update_kernel.lower(*args).compile()
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+                       compiled.as_text())
+    assert len(calls) == 1 and calls[0].startswith("ssm_state_update")
+
+
+def test_granite_hybrid_decode_step_compiles(one_chip):
+    """granite-4.0-h-small's served decode step at its benchmark slots,
+    with the paged kernel and the state-update kernel in the program."""
+    import json
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from chipbench import arch, run
+    from repro.serving.runner import init_pools, make_step_fns
+
+    cj = json.loads((root / "chipbench/configs/"
+                     "granite-4.0-h-small-pp4-ep4.json").read_text())
+    cfg = run.model_config(cj)
+    e = cj["engine"]
+    B, P, page = e["max_batch"], e["max_pages_per_seq"], e["page_size"]
+    params = jax.eval_shape(lambda: arch.resolve(cj).program_params(cj, 0))
+    pools = jax.eval_shape(lambda: init_pools(cfg, B * P + 1, page, B))
+    args = _on(one_chip, (
+        params, pools,
+        jax.ShapeDtypeStruct((B, P), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.bool_)))
+    _, decode_fn = make_step_fns(cfg, page, use_kernel=True)
+    text = decode_fn.lower(*args).compile().as_text()
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+                       text)
+    assert any(c.startswith("ssm_state_update") for c in calls)
+    assert any(c.startswith("paged_attention") for c in calls)
