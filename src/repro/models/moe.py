@@ -79,11 +79,14 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
     E, K, Er = cfg.num_experts, cfg.top_k, cfg.routed
     T = B * S
     Sg = min(cfg.group_size, T)
-    G = T // Sg
-    assert G * Sg == T, f"group_size {Sg} must divide tokens {T}"
+    G = -(-T // Sg)
     cap = max(K, int(math.ceil(Sg * K / E * cfg.capacity_factor)))
 
-    xg = x.reshape(G, Sg, d)
+    # a last, partial group is filled with zero tokens; they follow the
+    # real ones in every expert's queue, so they take no slot from them
+    pad = G * Sg - T
+    xg = (jnp.pad(x.reshape(T, d), ((0, pad), (0, 0))) if pad
+          else x).reshape(G, Sg, d)
     with jax.named_scope("route"):
         logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32),
                             p["router"])
@@ -138,7 +141,7 @@ def apply_moe(p, x: jnp.ndarray, cfg: MoEConfig, mlp_kind: str,
 
     with jax.named_scope("combine"):
         out = jnp.einsum("gsec,egcd->gsd", combine_t.astype(cdt), out_e)
-        out = out.reshape(B, S, d)
+        out = (out.reshape(G * Sg, d)[:T] if pad else out).reshape(B, S, d)
     if "shared" in p:
         from repro.models.layers import apply_mlp
         with jax.named_scope("shared"):

@@ -44,6 +44,7 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.serving.executors import prefill_len
 from repro.serving.kv_cache import PageManager
 from repro.serving.metrics import MetricsRegistry
 from repro.serving.overload import OverloadPolicy
@@ -406,13 +407,15 @@ class Engine:
 
     def _prefill_tokens(self, batch: List[Request]) -> np.ndarray:
         """Materialise the padded token matrix (only if the executor reads
-        token values; the sim tier meters counts and timing only). Every
-        row is computed at the matrix's width: its size is what
-        `repro:prefill_tokens_computed_total` counts."""
+        token values; the sim tier meters counts and timing only). Its
+        size, every row at the matrix's width, is what
+        `repro:prefill_tokens_computed_total` counts: the matrix handed to
+        the executor. What a `RealExecutor` runs, each admitted row at its
+        own padded length, is `repro:prefill_tokens_run_total`."""
         B = self.cfg.max_batch
         if not getattr(self.ex, "needs_tokens", True):
             return np.zeros((B, 0), np.int32)
-        lp = -(-max(r.prompt_len for r in batch) // 64) * 64
+        lp = prefill_len(max(r.prompt_len for r in batch))
         tokens = np.zeros((B, lp), np.int32)
         rng = np.random.default_rng(batch[0].rid)
         for r in batch:
@@ -421,6 +424,14 @@ class Engine:
                    rng.integers(0, 1000, r.prompt_len))
             tokens[r.slot, :r.prompt_len] = row[:r.prompt_len]
         return tokens
+
+    @staticmethod
+    def _prefill_tokens_run(batch: List[Request], tokens: np.ndarray) -> int:
+        """The admitted rows at their own padded lengths, as the executor
+        runs them (none where it reads no token matrix)."""
+        if not tokens.size:
+            return 0
+        return sum(prefill_len(r.prompt_len) for r in batch)
 
     def _count_kv_pages(self, context_lens: np.ndarray, steps: int):
         """Per decode step, the pages of K/V the running rows' attention
@@ -525,6 +536,8 @@ class Engine:
                     self.metrics.inc("repro:prompt_tokens_total", n_prompt)
                     self.metrics.inc("repro:prefill_tokens_computed_total",
                                      tokens.size)
+                    self.metrics.inc("repro:prefill_tokens_run_total",
+                                     self._prefill_tokens_run(batch, tokens))
                     self.metrics.inc("repro:generation_tokens_total",
                                      len(batch))
                     self._observe_ttfts(batch)
@@ -660,6 +673,8 @@ class Engine:
                     self._advance(dt)
                     self.metrics.inc("repro:prefill_tokens_computed_total",
                                      tokens.size)
+                    self.metrics.inc("repro:prefill_tokens_run_total",
+                                     self._prefill_tokens_run(batch, tokens))
                     for r in batch:
                         r.state = RequestState.RUNNING
                         r.tokens_out = 1

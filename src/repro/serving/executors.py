@@ -28,6 +28,15 @@ import numpy as np
 
 from repro.serving.tracing import span
 
+# prefill lengths are padded up to a multiple of this: the engine's token
+# matrix to its longest row's, each row the executor runs to its own
+PREFILL_PAD = 64
+
+
+def prefill_len(n: int) -> int:
+    """The padded length a prompt of `n` tokens is prefilled at."""
+    return -(-n // PREFILL_PAD) * PREFILL_PAD
+
 
 class RealExecutor:
     """Wall-clock tier: real logits, real latencies on the local device.
@@ -61,19 +70,44 @@ class RealExecutor:
     def prefill(self, tokens: np.ndarray, lens: np.ndarray,
                 do_mask: np.ndarray, block_tables: np.ndarray
                 ) -> Tuple[np.ndarray, float]:
+        """One `prefill_fn` call for each admitted slot, in slot order, on
+        that row alone at its own padded length (`prefill_len`); all are
+        dispatched back to back and waited on once. An empty mask runs one
+        inert row (slot `max_batch`, nothing kept) at the matrix's width:
+        the program a row of that length runs. Returns `first`, indexed
+        by slot."""
         import time
         jax, jnp = self._jax, self._jnp
         t0 = time.perf_counter()
-        with span("executor.prefill.inputs"):
-            bt, tokens, lens, do_mask = (
-                jnp.asarray(block_tables), jnp.asarray(tokens),
-                jnp.asarray(lens), jnp.asarray(do_mask))
-        with span("executor.prefill.launch"):
-            first, self.pools, self.seq_lens = self.prefill_fn(
-                self.params, self.pools, bt, self.seq_lens, tokens, lens,
-                do_mask)
+        B = self.seq_lens.shape[0]
+        rows = [(int(s), prefill_len(int(lens[s])))
+                for s in np.flatnonzero(do_mask)]
+        firsts = []
+        for slot, lp in rows or [(B, tokens.shape[1])]:
+            with span("executor.prefill.inputs"):
+                if slot < B:
+                    row = slice(slot, slot + 1)
+                    bt, tok = block_tables[row], tokens[row, :lp]
+                    ln, do = lens[row], do_mask[row]
+                else:
+                    bt = np.zeros((1, block_tables.shape[1]), np.int32)
+                    tok = np.zeros((1, lp), np.int32)
+                    ln, do = np.zeros(1, np.int32), np.zeros(1, bool)
+                bt, tok, ln, do, sl = (
+                    jnp.asarray(bt, jnp.int32), jnp.asarray(tok, jnp.int32),
+                    jnp.asarray(ln, jnp.int32), jnp.asarray(do, bool),
+                    jnp.asarray([slot], jnp.int32))
+            with span("executor.prefill.launch"):
+                f, self.pools, self.seq_lens = self.prefill_fn(
+                    self.params, self.pools, bt, self.seq_lens, tok, ln, do,
+                    sl)
+            firsts.append(f)
         with span("executor.prefill.wait"):
-            first = np.asarray(jax.block_until_ready(first))
+            jax.block_until_ready(firsts[-1])
+            got = jax.device_get(firsts)
+        first = np.zeros(B, np.int32)
+        for (slot, _), f in zip(rows, got):
+            first[slot] = f[0]
         return first, time.perf_counter() - t0
 
     def decode(self, tokens: np.ndarray, active: np.ndarray,

@@ -1,8 +1,10 @@
 """Jitted model execution against the paged KV pool (real-execution tier).
 
 Fixed-shape, mask-driven step functions over `max_batch` slots:
-  prefill_fn — process padded prompts for newly admitted slots, scatter K/V
-               into their pages, emit the first sampled token (TTFT event).
+  prefill_fn — process the padded prompts of the rows it is given (one
+               admitted request a call in `RealExecutor`), write their
+               K/V into their pages and their states into their slots,
+               emit the first sampled token (TTFT event).
   decode_fn  — one token for every active slot via the paged-attention op
                (the Pallas kernel on a TPU, the jnp reference elsewhere).
 Named scopes tile both steps (`embed`; `attention`, with its K/V write in
@@ -20,7 +22,6 @@ the dry-run covers their serve path).
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -94,6 +95,48 @@ def _scatter_kv(pool_k, pool_v, k, v, block_tables, positions, active,
     pool_k = pool_k.at[:, pf, of].set(kf.astype(pool_k.dtype))
     pool_v = pool_v.at[:, pf, of].set(vf.astype(pool_v.dtype))
     return pool_k, pool_v
+
+
+def _write_kv(pool_k, pool_v, k, v, block_tables, active, page_size: int):
+    """Write the rows' K/V into stacked (L, Hkv, P, page, D) pools: each
+    (page, offset) an active token maps to takes that token's K/V, every
+    other keeps its value. k/v: (L, R, T, Hkv, D); block_tables (R, P_max);
+    active (R, T) bool.
+
+    Each pool page takes its row page through a one-hot matmul, selected in
+    one pass over the pool in the pool's own layout. (XLA:TPU lays a D=64
+    pool out pages-minor; a scatter into its pages, as `_scatter_kv`
+    makes, transposes the whole pool there and back.)
+    """
+    L, R, T, H, D = k.shape
+    P = pool_k.shape[2]
+    n = -(-T // page_size)                      # pages a row's tokens span
+    pad = n * page_size - T
+    act = jnp.pad(active, ((0, 0), (0, pad)))                 # (R, n*page)
+    pos = jnp.arange(n * page_size)
+    cols = jnp.minimum(pos // page_size, block_tables.shape[1] - 1)
+    pages = jnp.where(act, block_tables[:, cols], P)      # P: not written
+    # src[p, o]: the token (of the rows' R * n * page) that slot o of pool
+    # page p takes, or -1
+    src = jnp.full((P, page_size), -1, jnp.int32).at[
+        pages, pos % page_size].set(
+        jnp.arange(R * n * page_size, dtype=jnp.int32).reshape(R, -1),
+        mode="drop")
+    onehot = jax.nn.one_hot(src.max(axis=1) // page_size, R * n,
+                            dtype=k.dtype)                     # (P, R*n)
+    keep = (src < 0)[None, None, :, :, None]
+
+    def put(pool, new):
+        new = jnp.pad(new, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+        # the matmul multiplies every row page by each pool page's 0 or 1:
+        # a token that is not written must not carry a NaN into it
+        new = jnp.where(act[None, :, :, None, None], new, 0)
+        new = new.reshape(L, R * n, page_size, H, D).transpose(0, 3, 1, 2, 4)
+        got = jnp.einsum("pj,lhjod->lhpod", onehot, new,
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+        return jnp.where(keep, pool, got.astype(pool.dtype))
+    return put(pool_k, k), put(pool_v, v)
 
 
 def make_decode_logits(cfg: ModelConfig, page_size: int, qcfg=None,
@@ -192,46 +235,45 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
         return nxt, pools, seq_lens
 
     # -- prefill ------------------------------------------------------------
-    @functools.partial(jax.jit, static_argnames=())
+    @jax.jit
     def prefill_fn(params, pools, block_tables, seq_lens, tokens, lens,
-                   do_prefill):
-        """tokens: (B, Lpad) int32; lens: (B,); do_prefill: (B,) bool.
+                   do_prefill, slots=None):
+        """Prefill the prompts of the call's rows: tokens (R, Lp) int32;
+        lens, do_prefill and slots (R,); block_tables (R, P), the rows'
+        own tables. `slots=None` means row r is slot r (a full-batch call).
 
-        Processes prompts for flagged slots; returns (first_tokens, pools,
-        seq_lens) with seq_lens set to lens for those slots.
+        Returns (first_tokens (R,), pools, seq_lens): each flagged row's
+        K/V in its pages, its recurrent state and its seq_len (= lens) at
+        its slot. Unflagged rows write nothing.
         """
-        B, Lp = tokens.shape
+        R, Lp = tokens.shape
+        if slots is None:
+            slots = jnp.arange(R, dtype=jnp.int32)
         with jax.named_scope("embed"):
             x = model_lib.embed_tokens(params, cfg, tokens)
-            positions = model_lib._positions(cfg, B, Lp)
+            positions = model_lib._positions(cfg, R, Lp)
             tok_active = (jnp.arange(Lp)[None] < lens[:, None]) & \
                 do_prefill[:, None]
 
-        def body(x, xs):
-            stacked_p, pools_r = xs
-            new_pools = []
+        # the scan carries the rows' activations only: each layer's K/V
+        # or recurrent state leaves it as that layer's output
+        def body(x, stacked_p):
+            outs = []
             for j, (kind, is_moe) in enumerate(pattern):
-                p, pool = stacked_p[j], pools_r[j]
+                p = stacked_p[j]
                 if kind == "attn":
                     with jax.named_scope("attention"):
                         h = apply_norm(p["ln1"], x, cfg.norm_kind)
                         q, k, v = attn_lib.qkv(p["attn"], h, cfg.num_heads,
                                                cfg.num_kv_heads, hd, qcfg)
-                        pos2 = model_lib._positions(cfg, B, Lp)
-                        q = attn_lib.rotate(cfg.rope_kind, q, pos2,
+                        q = attn_lib.rotate(cfg.rope_kind, q, positions,
                                             cfg.rope_theta)
-                        k = attn_lib.rotate(cfg.rope_kind, k, pos2,
+                        k = attn_lib.rotate(cfg.rope_kind, k, positions,
                                             cfg.rope_theta)
                         o = attn_lib.causal_attention(q, k, v, kv_len=lens)
-                        x = x + linear(o.reshape(B, Lp, cfg.num_heads * hd),
+                        x = x + linear(o.reshape(R, Lp, cfg.num_heads * hd),
                                        p["attn"]["wo"], qcfg)
-                        with jax.named_scope("kv_write"):
-                            posmat = jnp.broadcast_to(jnp.arange(Lp)[None],
-                                                      (B, Lp))
-                            pk, pv = _scatter_kv(pool["k"], pool["v"], k, v,
-                                                 block_tables, posmat,
-                                                 tok_active, page_size)
-                    new_pools.append({"k": pk, "v": pv})
+                    outs.append((k, v))
                 else:
                     with jax.named_scope(kind):
                         h = apply_norm(p["ln1"], x, cfg.norm_kind)
@@ -246,12 +288,27 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
                             y, st = xlstm_lib.slstm_seq(p, h, cfg.xlstm,
                                                         None, qcfg)
                         x = x + y
-                        new_pools.append(keep_rows(do_prefill, st, pool))
+                    outs.append(st)
                 x, _ = model_lib._apply_ff(p, cfg, x, is_moe, qcfg)
-            return x, tuple(new_pools)
+            return x, tuple(outs)
 
-        x, new_pools = jax.lax.scan(body, x, (params["blocks"],
-                                              tuple(pools)))
+        x, outs = jax.lax.scan(body, x, params["blocks"])
+
+        # write once, after the scan: K/V into the rows' pages, states and
+        # seq_lens at the rows' slots (unflagged rows' slots are dropped)
+        dest = jnp.where(do_prefill, slots, seq_lens.shape[0])
+        new_pools = []
+        for (kind, _), pool, out in zip(pattern, pools, outs):
+            if kind == "attn":
+                with jax.named_scope("attention/kv_write"):
+                    pk, pv = _write_kv(pool["k"], pool["v"], *out,
+                                       block_tables, tok_active, page_size)
+                new_pools.append({"k": pk, "v": pv})
+            else:
+                with jax.named_scope(kind):
+                    new_pools.append(jax.tree.map(
+                        lambda pl, st: pl.at[:, dest].set(
+                            st.astype(pl.dtype), mode="drop"), pool, out))
         with jax.named_scope("unembed"):
             x = apply_norm(params["final_norm"], x, cfg.norm_kind)
             # logits at each request's last prompt position
@@ -260,7 +317,7 @@ def make_step_fns(cfg: ModelConfig, page_size: int, qcfg=None,
                 x, idx[:, None, None].astype(jnp.int32), axis=1)
             logits = model_lib.unembed(params, cfg, x_last, qcfg)
             first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            new_seq = jnp.where(do_prefill, lens, seq_lens)
-        return first, list(new_pools), new_seq
+            new_seq = seq_lens.at[dest].set(lens, mode="drop")
+        return first, new_pools, new_seq
 
     return prefill_fn, decode_fn

@@ -167,3 +167,36 @@ def test_granite_hybrid_decode_step_compiles(one_chip):
                        text)
     assert any(c.startswith("ssm_state_update") for c in calls)
     assert any(c.startswith("paged_attention") for c in calls)
+
+
+@pytest.mark.parametrize("name", ["granite-moe-1b-a400m",
+                                  "phi3-medium-14b-pp4",
+                                  "granite-4.0-h-small-pp4-ep4"])
+def test_one_row_prefill_compiles(one_chip, name):
+    """The prefill a served request runs: one row at the longest padded
+    prompt the benchmark's mix forms (960 tokens), written into its own
+    slot, against each benchmark configuration's whole pool."""
+    import json
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from chipbench import arch, run
+    from repro.serving.runner import init_pools, make_step_fns
+
+    cj = json.loads((root / f"chipbench/configs/{name}.json").read_text())
+    cfg = run.model_config(cj)
+    e = cj["engine"]
+    B, P, page = e["max_batch"], e["max_pages_per_seq"], e["page_size"]
+    params = jax.eval_shape(lambda: arch.resolve(cj).program_params(cj, 0))
+    pools = jax.eval_shape(lambda: init_pools(cfg, B * P + 1, page, B))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    args = _on(one_chip, (params, pools, i32(1, P), i32(B), i32(1, 960),
+                          i32(1), jax.ShapeDtypeStruct((1,), jnp.bool_),
+                          i32(1)))
+    prefill_fn, _ = make_step_fns(cfg, page, use_kernel=True)
+    compiled = prefill_fn.lower(*args).compile()
+    out = jax.eval_shape(prefill_fn, *args)
+    assert out[0].shape == (1,) and out[2].shape == (B,)
+    assert compiled.memory_analysis().temp_size_in_bytes > 0

@@ -1,5 +1,5 @@
 """The live tier's own instrumentation on the CPU: the named scopes in the
-lowered step programs, the prefill counter on both scheduler paths, the
+lowered step programs, the prefill counters on both scheduler paths, the
 host-span helper off JAX, and the compile cache's key."""
 import os
 import re
@@ -34,9 +34,11 @@ def _op_names(step: str):
     if step == "decode":
         lowered = decode_fn.lower(params, pools, i32(B, MAXP), i32(B),
                                   i32(B), b1)
-    else:
-        lowered = prefill_fn.lower(params, pools, i32(B, MAXP), i32(B),
-                                   i32(B, 64), i32(B), b1)
+    else:                       # the call a served request makes: one row
+        lowered = prefill_fn.lower(params, pools, i32(1, MAXP), i32(B),
+                                   i32(1, 64), i32(1),
+                                   jax.ShapeDtypeStruct((1,), jnp.bool_),
+                                   i32(1))
     return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
 
 
@@ -89,6 +91,49 @@ def test_prefill_counter_counts_the_padded_matrix_on_both_paths():
         assert prompt == sum(r.prompt_len for r in reqs) < computed
         counted[fast] = (computed, prompt, list(shapes))
     assert counted[True] == counted[False]
+
+
+def test_prefill_run_counter_counts_the_admitted_rows_on_both_paths():
+    """The admitted rows at their own padded lengths, as the executor runs
+    them, the same on the fast and the reference scheduler."""
+    ex = build_engine(reduced("granite-moe-1b-a400m"), "real", max_batch=B,
+                      max_pages_per_seq=MAXP).ex
+    rows = []
+    prefill = ex.prefill
+
+    def recorded(tokens, lens, mask, *args):
+        rows.extend(-(-int(n) // 64) * 64 for n in lens[mask])
+        return prefill(tokens, lens, mask, *args)
+    ex.prefill = recorded
+    counted = {}
+    for fast in (True, False):
+        rows.clear()
+        eng = Engine(EngineConfig(max_batch=B, page_size=PAGE,
+                                  num_pages=B * MAXP + 1,
+                                  max_pages_per_seq=MAXP,
+                                  fast_forward=fast), ex)
+        reqs = _requests()
+        eng.run(reqs)
+        run = eng.metrics.get("repro:prefill_tokens_run_total")
+        assert len(rows) == len(reqs) and run == sum(rows)
+        prompt = eng.metrics.get("repro:prompt_tokens_total")
+        assert prompt <= run < eng.metrics.get(
+            "repro:prefill_tokens_computed_total")
+        counted[fast] = (run, list(rows))
+    assert counted[True] == counted[False]
+
+
+def test_prefill_run_counter_stays_zero_on_the_sim_tier():
+    from repro.configs import get_config
+    from repro.serving import ArrivalSpec, SimExecutor, synth_requests
+    from repro.simulate import V5E, StepTimeModel
+    cfg = get_config("llama31-8b")
+    for fast in (True, False):
+        eng = Engine(EngineConfig(max_batch=4, fast_forward=fast),
+                     SimExecutor(cfg, StepTimeModel(cfg, V5E)))
+        eng.run(synth_requests(ArrivalSpec(lam=5, n_requests=10, seed=0)))
+        assert eng.metrics.get("repro:prompt_tokens_total") > 0
+        assert eng.metrics.get("repro:prefill_tokens_run_total") == 0
 
 
 def test_sim_tier_stays_off_jax():
